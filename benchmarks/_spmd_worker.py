@@ -30,7 +30,7 @@ def main():
                     modulate=cfg_json.get("modulate", True),
                     n_kv_heads=cfg_json.get("n_kv_heads"),
                     dtype=jnp.float32)
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     if mode in ("hybrid", "layout2d"):
         # 2D SP process grid (outer DCN factor major) — launch.mesh
         outer = cfg_json.get("sp_outer") or 2

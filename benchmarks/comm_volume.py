@@ -179,7 +179,7 @@ def main(argv=None):
     # all-to-alls, so its instruction counts differ even though the moved
     # bytes match), reported on both fabrics
     from repro.core.layout import from_mesh
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.core.schedule import ScheduleExecutor
     from repro.models.lm import (LMConfig, dsp_schedule as lm_schedule,
                                  stage_period)

@@ -18,6 +18,7 @@ def spmd_measure(devices: int, mode: str, *, batch=2, temporal=8,
                reps=reps, overlap=overlap, n_kv_heads=n_kv_heads,
                sp_outer=sp_outer)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"     # a CPU simulation, never the chip
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(

@@ -210,6 +210,7 @@ def run_trace(devices: int, *, n_requests=16, max_batch=4, prompt_len=16,
                prompt_len=prompt_len, min_new=min_new, max_new=max_new,
                gap_steps=gap_steps, **extra)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"     # a CPU simulation, never the chip
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(

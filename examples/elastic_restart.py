@@ -33,7 +33,7 @@ ckpt_dir = sys.argv[1]
 cfg = LMConfig(name="elastic", n_layers=2, d_model=64, n_heads=4,
                n_kv_heads=2, head_dim=16, d_ff=128, vocab=64,
                dtype=jnp.float32)
-from repro.core.compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((4, 2), ("data", "model"))
 plan = ParallelPlan(mode="dsp")
 sharder = make_sharder(mesh, plan)
@@ -88,6 +88,7 @@ def main():
         print("phase1 (1 device):", out1["history"])
 
         # phase 2: resume on 8 simulated devices with sharded params
+        env["JAX_PLATFORMS"] = "cpu"     # simulated devices, never the chip
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         proc = subprocess.run([sys.executable, "-c", PHASE2, ckpt],
                               env=env, capture_output=True, text=True,

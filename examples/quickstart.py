@@ -31,7 +31,7 @@ ref = forward(params, x, t, cfg, backend="ref", remat=False)
 
 # DSP on a (data=2, model=4) mesh: sequence sharded on T, dynamically
 # switched to S for the temporal stage — one all-to-all per switch
-from repro.core.compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((2, 4), ("data", "model"))
 dsp_fwd = jax.jit(make_spmd_forward(cfg, mesh, mode="dsp", backend="ref"))
 out = dsp_fwd(params, x, t)

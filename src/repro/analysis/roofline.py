@@ -104,10 +104,12 @@ def _split_computations(hlo: str) -> Dict[str, List[str]]:
 
 def _trip_count(cond_lines: List[str]) -> int:
     """Recover lax.scan trip count from the while condition: the comparison
-    constant (direction=LT) is the bound."""
+    constant (direction=LT) is the bound.  The TPU compiler prints a layout
+    after the scalar type (``s32[]{:T(128)}``)."""
     consts = {}
     for ln in cond_lines:
-        m = re.match(r"%?([\w.\-]+)\s*=\s*s32\[\]\s*constant\((\d+)\)", ln)
+        m = re.match(r"%?([\w.\-]+)\s*=\s*s32\[\](?:\{[^}]*\})?\s*"
+                     r"constant\((\d+)\)", ln)
         if m:
             consts[m.group(1)] = int(m.group(2))
     for ln in cond_lines:

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import compat
 from repro.core.layout import SeqLayout, ParallelContext
 
 # ---------------------------------------------------------------------------
@@ -41,7 +40,7 @@ def dynamic_switch(x: jax.Array, cur_shard: int, tgt_shard: int,
     """
     if cur_shard == tgt_shard:
         return x
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if x.shape[tgt_shard] % n:
         raise ValueError(
             f"dynamic_switch: dim {tgt_shard} (size {x.shape[tgt_shard]}) "
@@ -55,7 +54,7 @@ def split(x: jax.Array, tgt_shard: int, axis_name: str = "model") -> jax.Array:
 
     Zero communication (paper Table 2 row ``s_hat -> s_i``).
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     if x.shape[tgt_shard] % n:
         raise ValueError(

@@ -33,7 +33,6 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core import compat
 
 # executor overlap modes (None = synchronous one-shot all-to-all)
 OVERLAP_MODES = (None, "chunked", "double_buffer")
@@ -54,7 +53,7 @@ def ring_stream(blocks, carry, fold: Callable, *,
     rotation happens AFTER the fold, every step including the last — n hops
     move exactly the blocks' full global bytes (the Table-3 ring volume the
     benchmarks measure).  ``carry`` leaves must already be vma-varying over
-    ``axis_name`` under shard_map (``compat.pvary``); constants are fine as
+    ``axis_name`` under shard_map (``jax.lax.pcast(..., to="varying")``); constants are fine as
     blocks.
 
     Args:
@@ -70,7 +69,7 @@ def ring_stream(blocks, carry, fold: Callable, *,
     Returns:
       the folded carry.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     steps = n if steps is None else steps
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -134,7 +133,7 @@ def overlapped_switch(x: jax.Array, src: int, tgt: int,
                          f"('chunked', 'double_buffer')")
     if src == tgt:
         return x
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if x.shape[tgt] % n:
         raise ValueError(
             f"overlapped_switch: dim {tgt} (size {x.shape[tgt]}) "

@@ -14,7 +14,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import compat
 from repro.core.overlap import ring_stream
 
 NEG_INF = -1e30
@@ -86,7 +85,8 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     valid0 = jnp.zeros((b, h, s_local), bool)
     # mark constant-initialised carries as varying over the ring axis so the
     # scan carry types line up under shard_map's vma tracking
-    carry0 = compat.pvary((o0, m0, l0, valid0), (axis_name,))
+    carry0 = jax.lax.pcast((o0, m0, l0, valid0), (axis_name,),
+                           to="varying")
     # the shared chunk/rotate helper (one ppermute hop per K/V block)
     o, m, l, any_valid = ring_stream((k, v), carry0, fold,
                                      axis_name=axis_name)

@@ -38,15 +38,17 @@ array shapes pin each cotangent to its primal's layout.
 Planned backwards compose with ``jax.lax.scan``: a scan-periodic schedule
 with distinct ``bwd_dims`` (``Schedule.periodic`` validates the backward
 leg's periodicity too) lowers to per-period ``custom_vjp`` boundary
-constraints INSIDE the scanned layer loop.  The while body then carries
-the cotangent in the steady-state layout ``bwd_dims[period-1]`` (the wrap
-anchor pins it — see ``PeriodicSchedule.bwd_wrap``); the *seam* reshard —
-cotangent creation at the loss boundary in the ``final`` layout — lands
-ONCE, on the backward loop's carry init outside the body, and the input
+constraints INSIDE the scanned layer loop.  Every cotangent inside the
+while body is pinned, but the loop carry between periods is the
+compiler's to place: in ``bwd_dims[0]`` (where stage 0's anchor leaves
+it) or in ``bwd_dims[period-1]`` (where the wrap takes it), with the same
+count per iteration either way.  The *seam* reshard — cotangent creation
+at the loss boundary in the ``final`` layout — lands ONCE, outside the
+body, followed by the reshard into a ``bwd_dims[0]`` carry; the input
 gradient's return to ``initial`` lands once after the loop.
 ``ScheduleExecutor.expected_bwd_collectives`` accounts exactly this
-executed structure (what the compiled HLO must show), next to
-``Schedule.bwd_transitions`` which prices the unrolled leg.
+executed structure for either carry (what the compiled HLO must show),
+next to ``Schedule.bwd_transitions`` which prices the unrolled leg.
 
 Models declare ``stages(cfg)`` and consume an executor; they never call
 ``dynamic_switch`` or issue stage-boundary sharding constraints themselves.
@@ -456,13 +458,17 @@ class PeriodicSchedule:
         bwd = self.bwd_dims
         return classify(bwd[-1], bwd[0])
 
-    def bwd_enter(self) -> Transition:
+    def bwd_enter(self, carry: str = "first") -> Transition:
         """Input gradient leaving the scan for the ``initial`` layout (the
         dataloader split owns both ends); lands once, after the loop.  A
-        stage-0-anchored body exits the carry in ``bwd_dims[0]``."""
+        stage-0-anchored body exits the carry in ``bwd_dims[0]``
+        (``carry="first"``); a compiler that holds the carry in
+        ``bwd_dims[-1]`` (``"last"``) runs the wrap at the end of each
+        iteration and exits from there."""
         initial = self.schedule.initial
         bwd = self.bwd_dims
-        return classify(bwd[0], initial if initial is not None else bwd[0])
+        src = bwd[0] if carry == "first" else bwd[-1]
+        return classify(src, initial if initial is not None else src)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -869,7 +875,8 @@ class ScheduleExecutor:
         add(self.psched.exit())
         return counts
 
-    def expected_bwd_collectives(self, n_periods: int = 1) -> Dict[str, int]:
+    def expected_bwd_collectives(self, n_periods: int = 1,
+                                 carry: str = "first") -> Dict[str, int]:
         """Collective counts of the EXECUTED backward leg (auto backend).
 
         Mirrored schedules transpose the forward constraints, so the leg
@@ -878,13 +885,14 @@ class ScheduleExecutor:
         scanned model in this repo is).  With a planned backward:
 
         * periodic (scanned) — the loss cotangent pays the SEAM
-          (``final -> bwd[-1]``) and the carry-init reshard into the
-          steady-state loop layout (``bwd[-1] -> bwd[0]``; a keep when the
-          period's first and last backward layouts agree, e.g. class-uniform
-          plans whose period starts and ends on a resid-class stage) ONCE,
-          outside the while body; each body iteration emits the reversed
-          in-period boundaries plus the wrap transition; the input gradient
-          returns to ``initial`` once, after the loop;
+          (``final -> bwd[-1]``) ONCE, outside the while body; each body
+          iteration emits the reversed in-period boundaries plus the wrap
+          transition; the input gradient returns to ``initial`` once, after
+          the loop.  ``carry`` says where the loop carry sits, which XLA
+          chooses: ``"first"`` (``bwd[0]``) adds the carry-init reshard
+          ``bwd[-1] -> bwd[0]`` after the seam and exits from ``bwd[0]``;
+          ``"last"`` (``bwd[-1]``) has no carry-init and exits from
+          ``bwd[-1]`` — never more collectives than ``"first"``;
         * unrolled — seam + every reversed absolute boundary + the input
           gradient's entry transition (``Schedule.bwd_transitions``).
 
@@ -909,13 +917,16 @@ class ScheduleExecutor:
                 add(tr)
             return counts
         ps = self.psched
+        if carry not in ("first", "last"):
+            raise ValueError(f"carry {carry!r}")
         add(ps.bwd_seam())                       # final -> bwd[-1], once
-        add(ps.bwd_carry_init())                 # into the loop carry, once
+        if carry == "first":
+            add(ps.bwd_carry_init())             # into the loop carry, once
         for _ in range(n_periods):
             for i in range(ps.period - 1, 0, -1):
                 add(ps.bwd_boundary(i))
             add(ps.bwd_wrap())
-        add(ps.bwd_enter())                      # input grad -> initial, once
+        add(ps.bwd_enter(carry))                 # input grad -> initial, once
         return counts
 
 

@@ -122,15 +122,6 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         softcap=softcap, kv_len=kv_len, q_offset=q_offset,
         block_q=block_q, block_k=block_k)
 
-    try:
-        # renamed across jax releases: CompilerParams <-> TPUCompilerParams
-        cp_cls = getattr(pltpu, "CompilerParams",
-                         getattr(pltpu, "TPUCompilerParams", None))
-        compiler_params = cp_cls(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
-    except (TypeError, AttributeError):  # older naming
-        compiler_params = None
-
     call = pl.pallas_call(
         kernel,
         grid=grid,
@@ -150,6 +141,8 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, LANES), jnp.float32),
         ],
         interpret=interpret,
-        **({"compiler_params": compiler_params} if compiler_params else {}),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
     )
     return call(q, k, v)

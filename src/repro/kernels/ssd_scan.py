@@ -90,15 +90,6 @@ def ssd_scan_fwd(xdt: jax.Array, da: jax.Array, b: jax.Array, c: jax.Array, *,
     grid = (bs, h, l // chunk)
     kernel = functools.partial(_ssd_kernel, chunk=chunk, group=group)
 
-    try:
-        # renamed across jax releases: CompilerParams <-> TPUCompilerParams
-        cp_cls = getattr(pltpu, "CompilerParams",
-                         getattr(pltpu, "TPUCompilerParams", None))
-        compiler_params = cp_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except (TypeError, AttributeError):
-        compiler_params = None
-
     call = pl.pallas_call(
         kernel,
         grid=grid,
@@ -115,6 +106,7 @@ def ssd_scan_fwd(xdt: jax.Array, da: jax.Array, b: jax.Array, c: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((bs, h, l, p), xdt.dtype),
         scratch_shapes=[pltpu.VMEM((p, s), jnp.float32)],
         interpret=interpret,
-        **({"compiler_params": compiler_params} if compiler_params else {}),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )
     return call(xdt, da, b, c)

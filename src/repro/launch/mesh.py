@@ -14,7 +14,6 @@ from __future__ import annotations
 
 from typing import Optional
 
-from repro.core import compat
 from repro.core.topology import Topology
 
 
@@ -23,12 +22,17 @@ def make_production_mesh(*, multi_pod: bool = False):
     ``pod`` axis: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh for tests/benchmarks (e.g. (8,) single-axis rings)."""
-    return compat.make_mesh(tuple(shape), tuple(axes))
+    """Mesh over all devices with every axis ``Auto``: the executor places
+    activations through sharding constraints, which ``jax.make_mesh``'s
+    default ``Explicit`` axes would turn into typed shardings."""
+    import jax
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def submesh(n_devices: int, data: int = 1, axis_names=("data", "model")):
@@ -68,9 +72,8 @@ def make_sp2d_mesh(outer: int, inner: int, dp: int = 1,
     (``core.ulysses.usp_attention``); DSP stages switch over the joint
     ("sp_out", "sp_in") axis pair.  ``dp > 1`` prepends a data axis."""
     if dp > 1:
-        return compat.make_mesh((dp, outer, inner),
-                                (dp_axis, "sp_out", "sp_in"))
-    return compat.make_mesh((outer, inner), ("sp_out", "sp_in"))
+        return make_mesh((dp, outer, inner), (dp_axis, "sp_out", "sp_in"))
+    return make_mesh((outer, inner), ("sp_out", "sp_in"))
 
 
 def sp2d_topology(outer: int, inner: int, *, placement=None) -> Topology:

@@ -110,6 +110,8 @@ def main(argv=None):
     if args.devices:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices}")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import jax
     import numpy as np

@@ -1,25 +1,33 @@
 """Training driver: ``python -m repro.launch.train --arch <id> [...]``.
 
-Runs a real (small-scale, CPU-friendly) training loop through the full
-production stack — config registry, parallel plan, AdamW, checkpointing,
-straggler watchdog — optionally on a simulated mesh (--devices N sets
-XLA_FLAGS before jax initialises; the production launcher would instead
-inherit the real TPU topology).
+Runs a training loop through the full stack — config registry, parallel
+plan, AdamW, checkpointing, straggler watchdog — on the devices JAX finds:
+the TPU chips of the host, or the CPU, where ``--devices N`` simulates N
+host devices (it sets XLA_FLAGS before jax initialises).
 
-Smoke-scale by default (the arch's SMOKE config); pass --full to train the
-published config (only sane on a real cluster).
+Smoke-scale by default (the arch's SMOKE config); ``--full`` trains the
+published config.  The paper's 720M DiT at full width on one TPU v5e::
+
+    python -m repro.launch.train --arch transformer2d-720m --full \
+        --batch 1 --temporal 16 --spatial 256 --steps 3
+
+and sequence-parallel over the four chips of one host with ``--mesh 1,4``.
 """
 import argparse
 import os
-import sys
 
 
-def main(argv=None):
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
-    ap.add_argument("--seq", type=int, default=128)
+    ap.add_argument("--seq", type=int, default=128,
+                    help="sequence length (lm / encdec)")
+    ap.add_argument("--temporal", type=int, default=8,
+                    help="video frames T (transformer2d)")
+    ap.add_argument("--spatial", type=int, default=16,
+                    help="tokens per frame S (transformer2d)")
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--resume", action="store_true")
@@ -31,22 +39,24 @@ def main(argv=None):
     ap.add_argument("--devices", type=int, default=0,
                     help="simulate N host devices (set before jax init)")
     ap.add_argument("--mesh", default=None,
-                    help="dp,mp mesh shape, e.g. 2,4 (requires --devices)")
+                    help="dp,mp mesh shape over all devices, e.g. 1,4")
     ap.add_argument("--full", action="store_true",
-                    help="use the full published config (cluster scale)")
-    args = ap.parse_args(argv)
+                    help="use the full published config")
+    return ap.parse_args(argv)
 
-    if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
 
+def build(args):
+    """The trainer ``main`` runs, built from parsed arguments: the model at
+    the chosen config, its loss, the data stream and — with ``--mesh`` —
+    params placed on the mesh and the solved DSP schedule.  Returns
+    ``(trainer, loss_fn)``."""
     import jax
-    import jax.numpy as jnp
     from repro import configs
     from repro.data.pipeline import DataConfig, make_batch
     from repro.optim.adamw import OptConfig
-    from repro.parallel.partition import make_sharder, ParallelPlan
-    from repro.train.trainer import ElasticSpec, Trainer, TrainerConfig
+    from repro.parallel.partition import make_sharder
+    from repro.train.trainer import (ElasticSpec, Trainer, TrainerConfig,
+                                     place_tree)
 
     spec = configs.get(args.arch)
     cfg = spec.config if args.full else spec.smoke
@@ -56,8 +66,7 @@ def main(argv=None):
     topology = None
     if args.mesh:
         dp, mp = (int(x) for x in args.mesh.split(","))
-        from repro.core.compat import make_mesh
-        from repro.launch.mesh import mesh_topology
+        from repro.launch.mesh import make_mesh, mesh_topology
         mesh = make_mesh((dp, mp), ("data", "model"))
         sharder = make_sharder(mesh, spec.plan)
         topology = mesh_topology(mesh, "ici")
@@ -106,20 +115,26 @@ def main(argv=None):
     else:
         from repro.models.transformer2d import dsp_schedule, init_t2d, t2d_loss
         params = init_t2d(jax.random.PRNGKey(0), cfg)
-        spatial = args.seq // 8 or 16
-        dcfg = DataConfig(task="video", batch=args.batch, temporal=8,
-                          spatial=spatial, in_dim=cfg.in_dim)
+        dcfg = DataConfig(task="video", batch=args.batch,
+                          temporal=args.temporal, spatial=args.spatial,
+                          in_dim=cfg.in_dim)
         psched = None
         if mesh is not None:
             psched = dsp_schedule(cfg, mesh.shape.get("model", 1),
-                                  t_len=8, s_len=spatial, batch=args.batch,
-                                  topology=topology, joint=True)
+                                  t_len=args.temporal, s_len=args.spatial,
+                                  batch=args.batch, topology=topology,
+                                  joint=True)
             schedule = psched.schedule
 
         def loss_fn(p, b):
-            return t2d_loss(p, b, cfg, mesh=mesh, backend="ref",
-                            schedule=psched)
+            # attention forward runs the Pallas flash kernel (compiled on
+            # the TPU, interpreted on the CPU)
+            return t2d_loss(p, b, cfg, mesh=mesh, schedule=psched)
 
+    if mesh is not None:
+        # init leaves params on the default device: put them on the mesh as
+        # the plan says (the optimizer state follows their shardings)
+        params = place_tree(params, mesh, spec.plan)
     trainer = Trainer(
         loss_fn=loss_fn, params=params,
         opt_cfg=OptConfig(peak_lr=args.lr, warmup_steps=max(args.steps // 10, 1),
@@ -131,6 +146,17 @@ def main(argv=None):
         data_fn=lambda s: make_batch(dcfg, s),
         ckpt_dir=args.ckpt_dir, schedule=schedule, mesh=mesh,
         topology=topology, elastic=elastic)
+    return trainer, loss_fn
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    if args.devices:
+        os.environ["XLA_FLAGS"] = (
+            f"--xla_force_host_platform_device_count={args.devices}")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    trainer, _ = build(args)
     if args.resume:
         trainer.try_resume()
     if args.replan:

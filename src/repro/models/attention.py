@@ -14,7 +14,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import compat
 from repro.kernels.ops import flash_attention
 from repro.models import layers as L
 
@@ -393,7 +392,7 @@ def chunked_attention(q, k, v, cfg: AttnConfig, *, mesh, layout: str,
                                     backend=backend, chunk=chunk)
             return o.transpose(0, 2, 1, 3)
 
-        fn = compat.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                            out_specs=spec, check_vma=False)
         return fn(q, k, v)
 
@@ -405,7 +404,7 @@ def chunked_attention(q, k, v, cfg: AttnConfig, *, mesh, layout: str,
                                        q_offset=0, backend=backend,
                                        chunk=chunk)
 
-        fn = compat.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                            out_specs=spec, check_vma=False)
         return fn(q, k, v)
 
@@ -420,7 +419,7 @@ def chunked_attention(q, k, v, cfg: AttnConfig, *, mesh, layout: str,
                                        q_offset=idx * s_loc, backend="ref",
                                        chunk=chunk)
 
-        fn = compat.shard_map(body, mesh=mesh, in_specs=(qspec, kvspec, kvspec),
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(qspec, kvspec, kvspec),
                            out_specs=qspec, check_vma=False)
         return fn(q, k, v)
 

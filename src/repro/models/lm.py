@@ -25,7 +25,6 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import compat
 from repro.core.plan import Stage
 from repro.core.schedule import Schedule, plan_joint_schedule, plan_schedule
 from repro.models import layers as L
@@ -420,14 +419,14 @@ def sharded_embed(params, tokens, cfg: LMConfig, sharder: Sharder):
             return acc + jnp.where(ok[..., None], e, 0)
 
         acc0 = jnp.zeros(tok.shape + (d,), tbl.dtype)
-        acc0 = compat.pvary(acc0, ("model",))
+        acc0 = jax.lax.pcast(acc0, ("model",), to="varying")
         return ring_stream(tbl, acc0, fold, axis_name="model")
 
     tok_spec = P(dp, "model") if seq_shard else P(dp, None)
     out_spec = P(dp, "model", None) if seq_shard else P(dp, None, None)
-    fn = compat.shard_map(local, mesh=mesh,
-                       in_specs=(P("model", None), tok_spec),
-                       out_specs=out_spec, check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                          in_specs=(P("model", None), tok_spec),
+                          out_specs=out_spec, check_vma=False)
     x = fn(table, tokens)
     if cfg.embed_scale:
         x = x * math.sqrt(d)

@@ -38,7 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import compat
 from repro.core import ring as ring_core
 from repro.core import ulysses as ulysses_core
 from repro.core import megatron_sp as megatron_core
@@ -567,7 +566,7 @@ def _megatron_block(p, x, cfg: T2DConfig, *, axis: int, t_emb=None,
     sequence, compute attention/MLP with locally-sliced heads / hidden
     (tensor parallel), ReduceScatter partial outputs back.  4 collectives,
     volume 4M per block (8M per 2-block layer)."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, t_loc, s, c = x.shape
     h_heads, dh = cfg.n_heads, cfg.dh
@@ -922,7 +921,7 @@ def make_spmd_forward(cfg: T2DConfig, mesh: Mesh, *, mode: str = "dsp",
     seq_entry = sp_axes if mode == "hybrid" else axis_name
     batch_spec = P(dp, seq_entry, None, None)
     t_spec = P(dp) if dp is not None else P()
-    fwd = compat.shard_map(
+    fwd = jax.shard_map(
         local_fwd, mesh=mesh,
         in_specs=(P(), batch_spec, t_spec),
         out_specs=batch_spec,

@@ -44,14 +44,17 @@ def schedule(cfg: OptConfig, step: jax.Array) -> jax.Array:
 
 
 def init_opt_state(params, cfg: OptConfig):
+    """Fresh state on the params' devices and shardings.  Every leaf is its
+    own buffer (the master is a copy even of f32 params), so a train step
+    may donate params and state together."""
     zeros = jax.tree_util.tree_map(
-        lambda p: jnp.zeros(p.shape, cfg.state_dtype), params)
+        lambda p: jnp.zeros_like(p, dtype=cfg.state_dtype), params)
     state = {"m": zeros,
              "v": jax.tree_util.tree_map(jnp.copy, zeros),
              "step": jnp.zeros((), jnp.int32)}
     if cfg.use_master:
         state["master"] = jax.tree_util.tree_map(
-            lambda p: p.astype(jnp.float32), params)
+            lambda p: jnp.array(p, dtype=jnp.float32, copy=True), params)
     return state
 
 

@@ -4,11 +4,11 @@
 update (optionally scanning microbatches for gradient accumulation and
 applying error-feedback int8 compression to the gradients that would cross
 the pod axis).  ``Trainer`` owns the host-side loop: periodic async
-checkpoints, resume-from-latest, deterministic data (stateless pipeline), a
-step-time EMA watchdog that flags stragglers, and retry-on-transient-failure
-around the device step (node-failure handling at the single-controller
-level; on a real fleet the same hook triggers the coordinator's
-shrink/regrow path and `restore()` onto the surviving mesh).
+checkpoints, resume-from-latest, deterministic data (stateless pipeline) and
+a step-time EMA watchdog that flags stragglers.  The jitted step donates
+params and optimizer state, so a failed step cannot be retried on the same
+arguments: a device error propagates at once, and recovery is
+``try_resume`` from the last checkpoint (or ``replan`` onto the survivors).
 """
 from __future__ import annotations
 
@@ -46,7 +46,7 @@ class ElasticSpec:
     plan: Any = None
 
 
-def _place_tree(tree, mesh, plan):
+def place_tree(tree, mesh, plan):
     """Migrate a params-shaped pytree onto ``mesh`` per ``plan``
     (``param_pspecs``-derived shardings; the path rules see the same leaf
     names under ``m/``/``v/``/``master/`` prefixes, so AdamW moments and
@@ -71,7 +71,6 @@ class TrainerConfig:
     log_every: int = 10
     ckpt_every: int = 50
     straggler_factor: float = 3.0      # step slower than 3x EMA => flagged
-    max_retries: int = 2               # transient-failure retries per step
     grad_compress: bool = False        # int8 EF compression (cross-pod)
 
 
@@ -124,7 +123,6 @@ class Trainer:
     def __init__(self, *, loss_fn, params, opt_cfg: OptConfig,
                  cfg: TrainerConfig, data_fn: Callable[[int], Any],
                  ckpt_dir: Optional[str] = None,
-                 jit_kwargs: Optional[dict] = None,
                  schedule=None, mesh=None, topology=None,
                  elastic: Optional[ElasticSpec] = None):
         self.cfg = cfg
@@ -135,18 +133,15 @@ class Trainer:
         self.residuals = (init_residuals(params) if cfg.grad_compress
                           else None)
         self.ckpt = (CheckpointManager(ckpt_dir) if ckpt_dir else None)
-        self._jit_kwargs = jit_kwargs
-        self.step_fn = jax.jit(
-            make_train_step(loss_fn, opt_cfg, grad_accum=cfg.grad_accum,
-                            grad_compress=cfg.grad_compress),
-            **(jit_kwargs or {}))
         self.start_step = 0
         self.straggler_events = []
         self.metrics_history = []
+        self.step_seconds = []     # host clock, step ended by block_until_ready
         # elastic state: the mesh/schedule the step runs on today, the
         # fabric template replan resizes, and the data-axis width an
         # elastic resize preserves when it still divides
         self.mesh = mesh
+        self.step_fn = self._jit_step(loss_fn)
         self.schedule = schedule
         self.elastic = elastic
         self._topology_template = (
@@ -158,6 +153,46 @@ class Trainer:
         # DSP Schedule (core.schedule) prices its forward AND its planned
         # backward — surfaced in the run() summary next to measured times
         self.plan_meta = self._plan_meta(schedule)
+
+    def _jit_step(self, loss_fn):
+        """The jitted train step.  Params and optimizer state are donated:
+        the update writes in place, which is what lets a full-width model's
+        step fit one chip (no second copy of the f32 master, m and v)."""
+        kw = {}
+        if self.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            kw["out_shardings"] = (self._pin_state()
+                                   + (NamedSharding(self.mesh, P()),))
+        return jax.jit(
+            make_train_step(loss_fn, self.opt_cfg,
+                            grad_accum=self.cfg.grad_accum,
+                            grad_compress=self.cfg.grad_compress),
+            donate_argnums=(0, 1), **kw)
+
+    def _pin_state(self):
+        """Put params, optimizer state (and residuals) on ``self.mesh`` —
+        leaves not on it yet, such as the step counter, replicated — and
+        return their shardings.  The step's outputs keep them, so the
+        donated buffers are reused in place and every call runs the same
+        executable (XLA's own choice of output sharding would differ from
+        the placement and recompile the second step)."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        rep = NamedSharding(self.mesh, P())
+
+        def where(x):
+            s = x.sharding
+            return (s if isinstance(s, NamedSharding) and s.mesh == self.mesh
+                    else rep)
+
+        state = (self.params, self.opt_state)
+        if self.cfg.grad_compress:
+            state += (self.residuals,)
+        shardings = jax.tree_util.tree_map(where, state)
+        placed = jax.device_put(state, shardings)
+        self.params, self.opt_state = placed[0], placed[1]
+        if self.cfg.grad_compress:
+            self.residuals = placed[2]
+        return shardings
 
     @staticmethod
     def _plan_meta(schedule) -> Optional[Dict[str, Any]]:
@@ -267,18 +302,14 @@ class Trainer:
                         if self.elastic.solve_schedule is not None and sp > 1
                         else None)
             sharder = make_sharder(mesh, plan, schedule, topo)
-        loss_fn = self.elastic.make_loss(mesh, sharder, schedule)
-        self.step_fn = jax.jit(
-            make_train_step(loss_fn, self.opt_cfg,
-                            grad_accum=self.cfg.grad_accum,
-                            grad_compress=self.cfg.grad_compress),
-            **(self._jit_kwargs or {}))
         # migrate live state: moments/master/residuals follow their params;
         # the scalar step count is replicated everywhere
-        self.params = _place_tree(self.params, mesh, plan)
-        self.opt_state = _place_tree(self.opt_state, mesh, plan)
-        self.residuals = _place_tree(self.residuals, mesh, plan)
+        self.params = place_tree(self.params, mesh, plan)
+        self.opt_state = place_tree(self.opt_state, mesh, plan)
+        self.residuals = place_tree(self.residuals, mesh, plan)
         self.mesh = mesh
+        self.step_fn = self._jit_step(
+            self.elastic.make_loss(mesh, sharder, schedule))
         self.schedule = schedule
         self.plan_meta = self._plan_meta(schedule)
         log.info("replanned onto %d device(s)%s", n_devices,
@@ -293,24 +324,16 @@ class Trainer:
         while step < self.cfg.total_steps:
             batch = self.data_fn(step)
             t0 = time.monotonic()
-            for attempt in range(self.cfg.max_retries + 1):
-                try:
-                    if self.cfg.grad_compress:
-                        (self.params, self.opt_state, self.residuals,
-                         metrics) = self.step_fn(self.params, self.opt_state,
-                                                 batch, self.residuals)
-                    else:
-                        self.params, self.opt_state, metrics = self.step_fn(
-                            self.params, self.opt_state, batch)
-                    jax.block_until_ready(metrics["loss"])
-                    break
-                except jax.errors.JaxRuntimeError:
-                    # transient device failure: retry, then restore+reraise
-                    log.warning("step %d attempt %d failed", step, attempt)
-                    if attempt == self.cfg.max_retries:
-                        self._checkpoint(step, blocking=True)
-                        raise
+            if self.cfg.grad_compress:
+                (self.params, self.opt_state, self.residuals,
+                 metrics) = self.step_fn(self.params, self.opt_state,
+                                         batch, self.residuals)
+            else:
+                self.params, self.opt_state, metrics = self.step_fn(
+                    self.params, self.opt_state, batch)
+            jax.block_until_ready(metrics["loss"])
             dt = time.monotonic() - t0
+            self.step_seconds.append(dt)
             if ema is None:
                 ema = dt
             if dt > self.cfg.straggler_factor * ema and step > self.start_step + 2:
@@ -329,6 +352,7 @@ class Trainer:
         self._checkpoint(step, blocking=True)
         out = {"final_step": step,
                "history": self.metrics_history,
+               "step_seconds": self.step_seconds,
                "stragglers": self.straggler_events}
         if self.plan_meta is not None:
             out["plan"] = self.plan_meta

@@ -99,8 +99,8 @@ def main():
     from jax.sharding import PartitionSpec as P
 
     from repro.analysis.roofline import parse_data_collectives
-    from repro.core import compat
     from repro.core.layout import from_mesh
+    from repro.launch.mesh import make_mesh
     from repro.core.plan import Stage
     from repro.core.schedule import Schedule, ScheduleExecutor
     from repro.models.transformer2d import (T2DConfig, dsp_schedule, forward,
@@ -110,7 +110,7 @@ def main():
     cfg = T2DConfig(name="hlo", n_layers=4, d_model=64, n_heads=4, d_ff=128,
                     in_dim=16, modulate=False, dtype=jnp.float32)
     b, t, s = 2, 8, 16
-    mesh = compat.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = from_mesh(mesh)
     params = init_t2d(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (b, t, s, cfg.in_dim))
@@ -131,9 +131,9 @@ def main():
                       params, x, tt)
 
     from repro.core.dsp import split as dsp_split
-    split_fn = compat.shard_map(
+    split_fn = jax.shard_map(
         lambda y: dsp_split(y, 1), mesh=mesh,
-        in_specs=P(None, None), out_specs=P(None, "model"))
+        in_specs=P(None, None), out_specs=P(None, "model"), check_vma=False)
     split_counts = counts(split_fn, jnp.zeros((4, 8), jnp.float32))
 
     # ---- overlapped switches (PR 6): decomposed permutes + parity ---------
@@ -236,6 +236,8 @@ def main():
         return {
             "planned_fwd": cex.expected_collectives(N_PERIODS),
             "planned_bwd": cex.expected_bwd_collectives(N_PERIODS),
+            "planned_bwd_last": cex.expected_bwd_collectives(N_PERIODS,
+                                                             carry="last"),
             "fwd": counts(loss, w, xx),
             "grad": counts(jax.grad(loss, argnums=(0, 1)), w, xx),
         }
@@ -286,7 +288,7 @@ def main():
                      d_ff=256, in_dim=16, modulate=False, n_kv_heads=4,
                      dtype=jnp.float32)
     hb, ht, hs = 2, 128, 4
-    hmesh = compat.make_mesh((2, 4), ("sp_out", "sp_in"))
+    hmesh = make_mesh((2, 4), ("sp_out", "sp_in"))
     hparams = init_t2d(jax.random.PRNGKey(5), hcfg)
     hx = jax.random.normal(jax.random.PRNGKey(6), (hb, ht, hs, hcfg.in_dim))
     htt = jnp.zeros((hb,))

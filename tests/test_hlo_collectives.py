@@ -38,6 +38,7 @@ SRC = os.path.join(HERE, "..", "src")
 @pytest.fixture(scope="module")
 def hlo_counts():
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"     # simulated devices, never the chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(
@@ -105,11 +106,15 @@ def test_synthetic_scan_planned_backward_per_leg_counts(hlo_counts):
     custom_vjp boundaries whose compiled backward leg shows EXACTLY the
     planned all-to-alls (``expected_bwd_collectives``): steady-state
     periodic leg inside the while body, seam + carry-init + input-grad
-    entry outside it.  The mirrored case is the control."""
+    entry outside it — for whichever of the two loop-carry layouts XLA
+    picks (jax 0.9.0 holds the ``swapped`` carry in ``bwd[-1]``, which
+    drops the carry-init and the input-grad reshard).  The mirrored case
+    is the control."""
     for name, case in hlo_counts["synthetic"].items():
         assert _a2a(case["fwd"]) == _a2a(case["planned_fwd"]), (name, case)
         bwd = _a2a(case["grad"]) - _a2a(case["fwd"])
-        assert bwd == _a2a(case["planned_bwd"]), (name, case)
+        assert bwd in (_a2a(case["planned_bwd"]),
+                       _a2a(case["planned_bwd_last"])), (name, case)
     # the contract distinguishes the legs: the forced plans' backward legs
     # differ from the mirrored control's
     syn = hlo_counts["synthetic"]

@@ -170,7 +170,7 @@ def test_unrolled_t2d_forward_matches_scan():
     reproduce the scanned path exactly (same plan, different execution)."""
     import jax
     import jax.numpy as jnp
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models.transformer2d import (T2DConfig, dsp_schedule, forward,
                                             init_t2d)
     cfg = T2DConfig(name="t", n_layers=4, d_model=32, n_heads=4, d_ff=64,
@@ -214,7 +214,7 @@ def test_planned_backward_gradient_parity():
     only, never math."""
     import jax
     import jax.numpy as jnp
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.core.layout import from_mesh
     planned, mirror = _parity_instance()
     mesh = make_mesh((1, 1), ("data", "model"))
@@ -250,7 +250,7 @@ def test_planned_backward_t2d_loss_gradient_parity():
     import dataclasses
     import jax
     import jax.numpy as jnp
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models.transformer2d import (T2DConfig, dsp_schedule, init_t2d,
                                             t2d_loss)
     cfg = T2DConfig(name="t", n_layers=2, d_model=32, n_heads=4, d_ff=64,
@@ -288,7 +288,7 @@ def test_periodic_planned_backward_seam_targets_last_stage(monkeypatch):
     targeting bwd_plan[0] would emit two collectives where the cost model
     prices one."""
     import repro.core.schedule as schedule_mod
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.core.layout import from_mesh
 
     # free stages over 3 dims: fwd parks on 3, bwd alternates 1/2 — feasible,

@@ -292,10 +292,9 @@ def test_sharder_layout_spec_two_axis_pspecs():
 
 
 def test_mesh_topology_sp2d_detection_and_loud_unknown_axis():
-    from repro.core import compat
-    from repro.launch.mesh import mesh_topology
+    from repro.launch.mesh import make_mesh, mesh_topology
 
-    mesh = compat.make_mesh((1, 1), ("sp_out", "sp_in"))
+    mesh = make_mesh((1, 1), ("sp_out", "sp_in"))
     topo = mesh_topology(mesh)
     assert [a.name for a in topo.axes] == ["dcn", "ici"]
     assert topo.size == 1

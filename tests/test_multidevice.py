@@ -29,6 +29,7 @@ SCENARIOS = [
 @pytest.mark.parametrize("name", SCENARIOS)
 def test_scenario(name):
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"     # simulated devices, never the chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(

@@ -60,7 +60,7 @@ def test_scanned_lm_forced_nonmirrored_gradient_parity():
     back — the forced plan would then silently execute the mirror, and the
     schedule handed to the Sharder would no longer carry ``bwd_dims``."""
     import jax
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models.lm import dsp_schedule, lm_loss
     from repro.parallel.partition import ParallelPlan, make_sharder
     cfg, params, batch = _lm_setup()
@@ -90,7 +90,7 @@ def test_scanned_lm_forced_parity_with_remat():
     """Same contract through ``jax.checkpoint`` — the recompute re-emits the
     forward constraints, the planned backward still only moves layouts."""
     import jax
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models.lm import dsp_schedule, lm_loss
     from repro.parallel.partition import ParallelPlan, make_sharder
     cfg, params, batch = _lm_setup()
@@ -112,7 +112,7 @@ def test_scanned_lm_forced_parity_with_remat():
 def test_encdec_forced_nonmirrored_gradient_parity():
     import jax
     import jax.numpy as jnp
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models.encdec import (EncDecConfig, dsp_schedule, encdec_loss,
                                      init_encdec)
     from repro.parallel.partition import ParallelPlan, make_sharder
@@ -191,28 +191,32 @@ def test_expected_bwd_collectives_periodic_accounting():
     numbers are compiled and counted on 8 devices by
     tests/test_hlo_collectives.py (synthetic scan worker cases)."""
     from repro.core.layout import from_mesh
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     ctx = from_mesh(make_mesh((1, 1), ("data", "model")))
     P = 3
 
-    def a2a(sched):
+    def a2a(sched, carry="first"):
         ex = ScheduleExecutor(sched.periodic(2), backend="auto", ctx=ctx)
-        return ex.expected_bwd_collectives(P).get("all-to-all", 0)
+        return ex.expected_bwd_collectives(P, carry=carry).get("all-to-all",
+                                                                0)
 
     # mirrored: the transposed forward (2 switches/period, free ends)
     mir = _free_periodic((1, 2) * P, None, initial=1, final=1)
-    assert a2a(mir) == 2 * P
-    # non-mirrored, seam/entry free: swap plan — 2/period + carry-init + entry
+    assert a2a(mir) == a2a(mir, "last") == 2 * P
+    # non-mirrored, seam/entry free: swap plan — 2/period + carry-init + entry;
+    # a carry held in bwd[-1] = initial needs neither
     swap = _free_periodic((1, 2) * P, (2, 1) * P, initial=1, final=1)
     assert a2a(swap) == 2 * P + 2
+    assert a2a(swap, "last") == 2 * P
     # forward parks on a third dim; backward alternates: seam + carry-init +
-    # 2/period + entry
+    # 2/period + entry (no carry-init when the carry sits in bwd[-1])
     park = _free_periodic((3,) * (2 * P), (1, 2) * P, initial=3, final=3)
     assert a2a(park) == 2 * P + 3
+    assert a2a(park, "last") == 2 * P + 2
     # steady-state class-uniform plan (period starts/ends on the same bwd
     # layout): carry-init and wrap are keeps — only the seam + entry remain
     flat = _free_periodic((1, 2) * P, (2, 2) * P, initial=1, final=1)
-    assert a2a(flat) == 2
+    assert a2a(flat) == a2a(flat, "last") == 2
 
 
 def test_periodic_bwd_views():

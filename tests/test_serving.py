@@ -319,7 +319,7 @@ def test_dryrun_cell_meta_records_profile_fabric(tmp_path):
     executed-vs-priced backward identity — in the cell meta."""
     import json
     from repro.configs import get
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.launch.mesh import resolve_topology as resolve
     from repro.launch.steps import build_cell
     samples = [[1 << 20, 1e-4], [1 << 24, 1.2e-3], [1 << 26, 4.6e-3]]
@@ -400,6 +400,7 @@ print("replan OK")
 
 def test_replan_sp_degree_change_matches_unsharded_reference():
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"     # simulated devices, never the chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", REPLAN_SCRIPT], env=env,
