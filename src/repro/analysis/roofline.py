@@ -206,14 +206,26 @@ def parse_collectives(hlo: str) -> CollectiveStats:
                            {k: v for k, v in by_count.items() if v})
 
 
-def parse_data_collectives(hlo: str) -> CollectiveStats:
+def op_scope(line: str) -> str:
+    """The innermost stage scope (``repro.tracing.SCOPES``) in the
+    ``op_name`` of one HLO instruction's text, or '' where it has none."""
+    from repro.tracing import SCOPES
+    m = re.search(r'op_name="([^"]*)"', line)
+    found = [t for t in re.split(r"[/();]", m.group(1))
+             if t in SCOPES] if m else []
+    return found[-1] if found else ""
+
+
+def parse_data_collectives(hlo: str, where=None) -> CollectiveStats:
     """``parse_collectives`` minus XLA partitioner artifacts: collectives
     whose every operand is a broadcast of a SCALAR CONSTANT.  When stage
     layouts alternate, the partitioner hoists constant broadcasts (norm eps,
     mean divisors) out of loop bodies and re-tiles them with real
     collectives that move zero information.  The HLO contract tests
     (tests/test_hlo_collectives.py) compare THIS count against the planned
-    schedule — one all-to-all per planned switch, on activations."""
+    schedule — one all-to-all per planned switch, on activations.
+    ``where``, given, keeps only the instructions whose text it accepts
+    (e.g. ``lambda ln: op_scope(ln) == "dsp_switch"``)."""
     comps = _split_computations(hlo)
     mult = _while_map(comps)
     defs: Dict[str, str] = {}
@@ -240,7 +252,7 @@ def parse_data_collectives(hlo: str) -> CollectiveStats:
     for cname, lines in comps.items():
         m = mult.get(cname, 1)
         for ln in lines:
-            if "=" not in ln:
+            if "=" not in ln or (where is not None and not where(ln)):
                 continue
             for kind in COLLECTIVES:
                 if re.search(rf"\s{kind}(?:-start)?\(", ln):

@@ -735,9 +735,19 @@ class ScheduleExecutor:
         backend with a planned-backward schedule only; ignored otherwise).
         ``consumer`` is the stage index whose kernel consumes the
         transitioned tensor — it selects the overlap mode for switches
-        (None, e.g. the exit transition, always runs synchronously)."""
+        (None, e.g. the exit transition, always runs synchronously).  The
+        transition's ops, and the collectives XLA inserts for them, carry
+        the ``dsp_switch`` scope (``repro.tracing``).  On a mirrored plan
+        the transposed constraint keeps the cotangent's layout, so the
+        backward's switch lands on the next constraint the cotangent meets,
+        a block's anchor, and carries that block's scope."""
         if self.backend == "null":
             return x
+        from repro import tracing
+        with tracing.scope(tracing.DSP_SWITCH):
+            return self._apply(x, tr, bwd_tgt, consumer)
+
+    def _apply(self, x, tr: Transition, bwd_tgt, consumer):
         if self.backend == "auto":
             # re-constrain even on "keep": anchors SPMD propagation at the
             # boundary, lowers to nothing when the layout is unchanged
@@ -1230,7 +1240,9 @@ class ScheduleExecutor2D:
     def apply(self, x, tr: PairTransition, **kw):
         if self.backend == "null":
             return x
-        return self.constrain(x, tr.tgt, **kw)
+        from repro import tracing
+        with tracing.scope(tracing.DSP_SWITCH):
+            return self.constrain(x, tr.tgt, **kw)
 
     # -- schedule-view conveniences -------------------------------------------
     def enter(self, x, **kw):

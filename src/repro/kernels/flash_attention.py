@@ -144,5 +144,6 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_fwd",
     )
     return call(q, k, v)

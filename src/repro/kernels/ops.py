@@ -16,6 +16,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.kernels import ref as _ref
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.ssd_scan import ssd_scan_fwd
@@ -72,8 +73,9 @@ def _make_attention(causal: bool, window: Optional[int],
 
     def attn_bwd(res, g):
         q, k, v = res
-        _, vjp = jax.vjp(ref_fn, q, k, v)
-        return vjp(g)
+        with tracing.scope(tracing.ATTN_BWD):
+            _, vjp = jax.vjp(ref_fn, q, k, v)
+            return vjp(g)
 
     attn.defvjp(attn_fwd, attn_bwd)
     return attn

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import tracing
 from repro.core import ring as ring_core
 from repro.core import ulysses as ulysses_core
 from repro.core import megatron_sp as megatron_core
@@ -493,7 +494,8 @@ def _default_attn(backend: str) -> AttnImpl:
 def _mod6(p, t_emb, cfg: T2DConfig):
     if not cfg.modulate or t_emb is None:
         return None
-    return L.modulation(p["mod"], t_emb)     # 6 x (B, 1, C)
+    with tracing.scope(tracing.ADALN):
+        return L.modulation(p["mod"], t_emb)     # 6 x (B, 1, C)
 
 
 def _modulate(h, shift, scale):
@@ -511,7 +513,6 @@ def t2d_block(p, x, cfg: T2DConfig, *, axis: int, t_emb=None,
     attn_impl = attn_impl or _default_attn(backend)
     b, t, s, c = x.shape
     h_heads, dh = cfg.n_heads, cfg.dh
-    mod = _mod6(p, t_emb, cfg)
 
     def fold(y):       # (B, T, S, C) -> (B*other, L, C)
         if axis == 1:
@@ -535,29 +536,41 @@ def t2d_block(p, x, cfg: T2DConfig, *, axis: int, t_emb=None,
         # audit — hundreds of GB of spurious all-to-alls)
         return stage_hook(y, axis) if stage_hook is not None else y
 
-    h = L.rms_norm(p["ln1"], x)
-    if mod is not None:
-        h = _modulate(h, bmod(mod[0]), bmod(mod[1]))
-    h = anchor(h)
-    hf = fold(h)
-    l = hf.shape[1]
-    q = L.linear(p["wq"], hf).reshape(-1, l, h_heads, dh)
-    k = L.linear(p["wk"], hf).reshape(-1, l, cfg.kvh, dh)
-    v = L.linear(p["wv"], hf).reshape(-1, l, cfg.kvh, dh)
-    o = attn_impl(q, k, v).reshape(-1, l, h_heads * dh)
-    o = anchor(unfold(L.linear(p["wo"], o)))
-    if mod is not None:
-        o = o * bmod(mod[2])
-    x = anchor(x + o)
+    with tracing.scope(tracing.TEMPORAL if axis == 1 else tracing.SPATIAL):
+        mod = _mod6(p, t_emb, cfg)
+        with tracing.scope(tracing.ADALN):
+            h = L.rms_norm(p["ln1"], x)
+            if mod is not None:
+                h = _modulate(h, bmod(mod[0]), bmod(mod[1]))
+        h = anchor(h)
+        hf = fold(h)
+        l = hf.shape[1]
+        with tracing.scope(tracing.PROJ):
+            q = L.linear(p["wq"], hf).reshape(-1, l, h_heads, dh)
+            k = L.linear(p["wk"], hf).reshape(-1, l, cfg.kvh, dh)
+            v = L.linear(p["wv"], hf).reshape(-1, l, cfg.kvh, dh)
+        with tracing.scope(tracing.ATTN):
+            o = attn_impl(q, k, v).reshape(-1, l, h_heads * dh)
+        with tracing.scope(tracing.PROJ):
+            o = L.linear(p["wo"], o)
+        o = anchor(unfold(o))
+        if mod is not None:
+            with tracing.scope(tracing.ADALN):
+                o = o * bmod(mod[2])
+        x = anchor(x + o)
 
-    h = L.rms_norm(p["ln2"], x)
-    if mod is not None:
-        h = _modulate(h, bmod(mod[3]), bmod(mod[4]))
-    h = anchor(h)
-    h = anchor(L.mlp(p["mlp"], h, cfg.mlp_kind))
-    if mod is not None:
-        h = h * bmod(mod[5])
-    return anchor(x + h)
+        with tracing.scope(tracing.ADALN):
+            h = L.rms_norm(p["ln2"], x)
+            if mod is not None:
+                h = _modulate(h, bmod(mod[3]), bmod(mod[4]))
+        h = anchor(h)
+        with tracing.scope(tracing.MLP):
+            h = L.mlp(p["mlp"], h, cfg.mlp_kind)
+        h = anchor(h)
+        if mod is not None:
+            with tracing.scope(tracing.ADALN):
+                h = h * bmod(mod[5])
+        return anchor(x + h)
 
 
 def _megatron_block(p, x, cfg: T2DConfig, *, axis: int, t_emb=None,
@@ -689,13 +702,14 @@ def forward(params, x, t, cfg: T2DConfig, *, mesh: Optional[Mesh] = None,
                                      layout="batch", causal=False,
                                      backend=backend)
 
-    x = L.patch_embed(params["embed"], x)
-    x = add_pos_embed(x, cfg, t_offset, s_offset)
+    with tracing.scope(tracing.EMBED):
+        x = L.patch_embed(params["embed"], x)
+        x = add_pos_embed(x, cfg, t_offset, s_offset)
+        t_emb = None
+        if cfg.modulate and t is not None:
+            t_emb = L.linear(params["t_proj"], L.timestep_embedding(
+                t, cfg.d_model).astype(x.dtype))
     x = ex.enter(x)                   # planned entry (dataloader split on T)
-    t_emb = None
-    if cfg.modulate and t is not None:
-        t_emb = L.linear(params["t_proj"],
-                         L.timestep_embedding(t, cfg.d_model).astype(x.dtype))
 
     layers = params["layers"]
     n = jax.tree_util.tree_leaves(layers)[0].shape[0]
@@ -720,12 +734,13 @@ def forward(params, x, t, cfg: T2DConfig, *, mesh: Optional[Mesh] = None,
                 xc = ex.boundary(xc, 2 * i + 2)
             return xc
 
-        for i in range(n):
-            lp = jax.tree_util.tree_map(lambda a: a[i], layers)
-            body = (jax.checkpoint(functools.partial(pair_body, i=i),
-                                   prevent_cse=False)
-                    if remat else functools.partial(pair_body, i=i))
-            x = body(x, lp)
+        with tracing.scope(tracing.LAYERS):
+            for i in range(n):
+                lp = jax.tree_util.tree_map(lambda a: a[i], layers)
+                body = (jax.checkpoint(functools.partial(pair_body, i=i),
+                                       prevent_cse=False)
+                        if remat else functools.partial(pair_body, i=i))
+                x = body(x, lp)
     else:
         def layer_body(xc, lp):
             # spatial stage: computes over S — planned shard stays on T
@@ -758,18 +773,21 @@ def forward(params, x, t, cfg: T2DConfig, *, mesh: Optional[Mesh] = None,
         body = (jax.checkpoint(group_body, prevent_cse=False) if remat
                 else group_body)
         from repro.models.flags import scan_or_unroll
-        x, _ = scan_or_unroll(body, x, grouped)
+        with tracing.scope(tracing.LAYERS):
+            x, _ = scan_or_unroll(body, x, grouped)
     x = ex.exit(x)                    # planned final layout (loss/head on T)
-    x = L.rms_norm(params["final_norm"], x)
-    return L.linear(params["head"], x)
+    with tracing.scope(tracing.LOSS):
+        x = L.rms_norm(params["final_norm"], x)
+        return L.linear(params["head"], x)
 
 
 def t2d_loss(params, batch, cfg: T2DConfig, **kw):
     """Diffusion-style MSE against target latents."""
     pred = forward(params, batch["x"], batch.get("t"), cfg, **kw)
-    err = (pred.astype(jnp.float32) -
-           batch["target"].astype(jnp.float32)) ** 2
-    return jnp.mean(err), {}
+    with tracing.scope(tracing.LOSS):
+        err = (pred.astype(jnp.float32) -
+               batch["target"].astype(jnp.float32)) ** 2
+        return jnp.mean(err), {}
 
 
 # ---------------------------------------------------------------------------

@@ -14,6 +14,8 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
+
 
 @dataclasses.dataclass(frozen=True)
 class OptConfig:
@@ -72,6 +74,11 @@ def clip_by_global_norm(grads, max_norm: float):
 
 def apply_adamw(params, grads, state, cfg: OptConfig):
     """One AdamW step.  Returns (new_params, new_state, metrics)."""
+    with tracing.scope(tracing.ADAMW):
+        return _adamw(params, grads, state, cfg)
+
+
+def _adamw(params, grads, state, cfg: OptConfig):
     step = state["step"] + 1
     lr = schedule(cfg, step)
     gnorm = jnp.zeros(())

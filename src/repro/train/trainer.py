@@ -5,7 +5,9 @@ update (optionally scanning microbatches for gradient accumulation and
 applying error-feedback int8 compression to the gradients that would cross
 the pod axis).  ``Trainer`` owns the host-side loop: periodic async
 checkpoints, resume-from-latest, deterministic data (stateless pipeline) and
-a step-time EMA watchdog that flags stragglers.  The jitted step donates
+a step-time EMA watchdog that flags stragglers, and names each step for the
+profiler (``run_step``: a step annotation around the host spans ``data``,
+``dispatch`` and ``sync``) and records recompiles.  The jitted step donates
 params and optimizer state, so a failed step cannot be retried on the same
 arguments: a device error propagates at once, and recovery is
 ``try_resume`` from the last checkpoint (or ``replan`` onto the survivors).
@@ -20,6 +22,7 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core.plan import JointPlan, StrategyPlan
 from repro.optim.adamw import OptConfig, apply_adamw, init_opt_state
 from repro.optim.compress import compress_with_feedback, init_residuals
@@ -135,6 +138,7 @@ class Trainer:
         self.ckpt = (CheckpointManager(ckpt_dir) if ckpt_dir else None)
         self.start_step = 0
         self.straggler_events = []
+        self.recompiles = []       # (step, programs compiled) after step 1
         self.metrics_history = []
         self.step_seconds = []     # host clock, step ended by block_until_ready
         # elastic state: the mesh/schedule the step runs on today, the
@@ -255,11 +259,12 @@ class Trainer:
         meta = None
         if sch is not None:
             meta = {"initial": sch.initial, "final": sch.final}
-        self.ckpt.save(step, tree, blocking=blocking,
-                       plan=self._plan_record(),
-                       topology=topo if topo is not None
-                       else self._topology_template,
-                       meta=meta)
+        with tracing.span(tracing.CHECKPOINT):
+            self.ckpt.save(step, tree, blocking=blocking,
+                           plan=self._plan_record(),
+                           topology=topo if topo is not None
+                           else self._topology_template,
+                           meta=meta)
 
     # -- elastic resize --------------------------------------------------------
     def replan(self, n_devices: int, *, topology=None):
@@ -318,22 +323,41 @@ class Trainer:
         return self
 
     # -- loop -------------------------------------------------------------------
+    def run_step(self, step: int):
+        """One training step inside the profiler's step annotation and the
+        host spans ``data``, ``dispatch`` and ``sync`` (``repro.tracing``);
+        returns its metrics and host seconds (dispatch to the loss being
+        ready).  Programs compiled, or loaded from the compile cache, by
+        any step after the first are recorded in ``recompiles`` as
+        ``(step, n)``."""
+        with _Compiles() as compiles, jax.profiler.StepTraceAnnotation(
+                tracing.STEP, step_num=step):
+            with tracing.span(tracing.DATA):
+                batch = self.data_fn(step)
+            t0 = time.monotonic()
+            with tracing.span(tracing.DISPATCH):
+                if self.cfg.grad_compress:
+                    (self.params, self.opt_state, self.residuals,
+                     metrics) = self.step_fn(self.params, self.opt_state,
+                                             batch, self.residuals)
+                else:
+                    self.params, self.opt_state, metrics = self.step_fn(
+                        self.params, self.opt_state, batch)
+            with tracing.span(tracing.SYNC):
+                jax.block_until_ready(metrics["loss"])
+            dt = time.monotonic() - t0
+        if compiles.n and self.step_seconds:
+            self.recompiles.append((step, compiles.n))
+            log.warning("recompile: step %d compiled %d program(s)", step,
+                        compiles.n)
+        self.step_seconds.append(dt)
+        return metrics, dt
+
     def run(self) -> Dict[str, Any]:
         ema = None
         step = self.start_step
         while step < self.cfg.total_steps:
-            batch = self.data_fn(step)
-            t0 = time.monotonic()
-            if self.cfg.grad_compress:
-                (self.params, self.opt_state, self.residuals,
-                 metrics) = self.step_fn(self.params, self.opt_state,
-                                         batch, self.residuals)
-            else:
-                self.params, self.opt_state, metrics = self.step_fn(
-                    self.params, self.opt_state, batch)
-            jax.block_until_ready(metrics["loss"])
-            dt = time.monotonic() - t0
-            self.step_seconds.append(dt)
+            metrics, dt = self.run_step(step)
             if ema is None:
                 ema = dt
             if dt > self.cfg.straggler_factor * ema and step > self.start_step + 2:
@@ -353,7 +377,28 @@ class Trainer:
         out = {"final_step": step,
                "history": self.metrics_history,
                "step_seconds": self.step_seconds,
-               "stragglers": self.straggler_events}
+               "stragglers": self.straggler_events,
+               "recompiles": self.recompiles}
         if self.plan_meta is not None:
             out["plan"] = self.plan_meta
         return out
+
+
+class _Compiles:
+    """Counts the programs JAX compiles or loads from the compile cache
+    while it is open."""
+
+    def __init__(self):
+        self.n = 0
+
+    def __call__(self, event, *_, **__):
+        if "backend_compile" in event or "cache_retrieval" in event:
+            self.n += 1
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self)
+        return False

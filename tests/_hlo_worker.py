@@ -20,7 +20,8 @@ Runs with XLA_FLAGS=--xla_force_host_platform_device_count=8; compiles
 
 and prints one JSON line with the parsed HLO collective counts next to the
 planned counts from the schedule executor
-(``expected_collectives`` / ``expected_bwd_collectives``).
+(``expected_collectives`` / ``expected_bwd_collectives``), and the counts
+of those that carry the ``dsp_switch`` scope.
 """
 import json
 import sys
@@ -98,7 +99,7 @@ def main():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.analysis.roofline import parse_data_collectives
+    from repro.analysis.roofline import op_scope, parse_data_collectives
     from repro.core.layout import from_mesh
     from repro.launch.mesh import make_mesh
     from repro.core.plan import Stage
@@ -119,6 +120,20 @@ def main():
     def counts(fn, *args):
         return _counts(parse_data_collectives, fn, *args)
 
+    def switch_counts(fn, *args):
+        """Counts of the collectives that carry the ``dsp_switch`` scope."""
+        return _counts(lambda txt: parse_data_collectives(
+            txt, where=lambda ln: op_scope(ln) == "dsp_switch"), fn, *args)
+
+    def block_anchor_bwd_counts(fn, *args):
+        """Counts of the backward's collectives at a block's own sharding
+        constraint (a transposed anchor)."""
+        def where(ln):
+            return ("transpose(" in ln and "/sharding_constraint" in ln
+                    and op_scope(ln) in ("spatial", "temporal"))
+        return _counts(lambda txt: parse_data_collectives(txt, where=where),
+                       fn, *args)
+
     # ---- forward contract (both backends + split) -------------------------
     psched = dsp_schedule(cfg, mesh.shape["model"], t_len=t, s_len=s, batch=b)
     ex = ScheduleExecutor(psched, backend="explicit")
@@ -127,6 +142,10 @@ def main():
     auto = counts(lambda p, xx, ttt: forward(p, xx, ttt, cfg, mesh=mesh,
                                              mode="dsp", backend="ref",
                                              remat=False), params, x, tt)
+    auto_switch = switch_counts(
+        lambda p, xx, ttt: forward(p, xx, ttt, cfg, mesh=mesh, mode="dsp",
+                                   backend="ref", remat=False),
+        params, x, tt)
     explicit = counts(make_spmd_forward(cfg, mesh, mode="dsp", backend="ref"),
                       params, x, tt)
 
@@ -193,6 +212,9 @@ def main():
         "planned_bwd": jex.expected_bwd_collectives(cfg.n_layers // 2),
         "fwd": counts(auto_loss, params),
         "grad": counts(jax.grad(auto_loss), params),
+        "grad_switch": switch_counts(jax.grad(auto_loss), params),
+        "grad_block_anchor_bwd": block_anchor_bwd_counts(jax.grad(auto_loss),
+                                                         params),
         "mirrored": jsched.schedule.mirrored,
     }
 
@@ -307,6 +329,7 @@ def main():
     print(json.dumps({
         "planned": planned,
         "auto": auto,
+        "auto_switch": auto_switch,
         "explicit": explicit,
         "split": split_counts,
         "n_periods": cfg.n_layers // 2,

@@ -101,6 +101,23 @@ def test_t2d_train_step_per_leg_counts(hlo_counts):
         _a2a(tr["explicit_fwd"]) + _a2a(tr["planned_bwd"]), tr
 
 
+def test_planned_switches_carry_the_dsp_switch_scope(hlo_counts):
+    """Every all-to-all of the planned forward carries the executor's
+    ``dsp_switch`` scope (``repro.tracing``), and so does the train step's
+    forward leg.  On the mirrored plan the transposed boundary constrains
+    the cotangent to the layout it already has, so each backward switch
+    lands on the first block-end anchor the cotangent meets: it carries
+    that block's scope under ``transpose(``."""
+    planned = hlo_counts["planned"]["all-to-all"]
+    assert _a2a(hlo_counts["auto_switch"]) == _a2a(hlo_counts["auto"])
+    assert _a2a(hlo_counts["auto_switch"]) == planned
+    tr = hlo_counts["t2d_train"]
+    assert _a2a(tr["grad_switch"]) == _a2a(tr["planned_fwd"]), tr
+    assert _a2a(tr["grad_block_anchor_bwd"]) == _a2a(tr["planned_bwd"]), tr
+    assert (_a2a(tr["grad_switch"]) + _a2a(tr["grad_block_anchor_bwd"])
+            == _a2a(tr["grad"])), tr
+
+
 def test_synthetic_scan_planned_backward_per_leg_counts(hlo_counts):
     """A scan-periodic schedule with distinct bwd_dims lowers to per-period
     custom_vjp boundaries whose compiled backward leg shows EXACTLY the

@@ -69,3 +69,93 @@ def test_temporal_stage_kernel_compiles(one_chip):
     # as kernels/ops.py pads it
     hlo = _compile_fwd(one_chip, 256, 16, 128, 16)
     assert "tpu_custom_call" in hlo
+
+
+def test_planned_switches_of_the_sharded_train_step_carry_their_scope(
+        topo, monkeypatch):
+    """The 720M train step at full width, cut to one spatial and one
+    temporal block, AOT-compiled for a (1, 4) DSP mesh of the v5e:2x2.
+    Every all-to-all of the forward, and of remat's recompute, is a planned
+    switch and carries ``dsp_switch``.  Each planned backward switch lands
+    on a block-end anchor (the mirrored plan's transposed boundary keeps
+    the cotangent's layout; see tests/test_hlo_collectives.py).  The
+    all-to-alls that no plan accounts for carry ``mlp``: they come from the
+    FFN's backward.  ``pytest -s`` prints the table by scope."""
+    import collections
+    import dataclasses
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+    from repro import configs
+    from repro.analysis.roofline import op_scope, parse_data_collectives
+    from repro.core.layout import from_mesh
+    from repro.core.schedule import ScheduleExecutor
+    from repro.kernels import flash_attention as fa, ops
+    from repro.launch.mesh import mesh_topology
+    from repro.models.transformer2d import dsp_schedule, init_t2d, t2d_loss
+    from repro.optim.adamw import OptConfig, init_opt_state
+    from repro.parallel.partition import param_pspecs
+    from repro.train.trainer import make_train_step
+
+    monkeypatch.setattr(ops, "flash_attention_fwd", functools.partial(
+        fa.flash_attention_fwd, interpret=False))
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    spec = configs.get("transformer2d-720m")
+    cfg = dataclasses.replace(spec.config, n_layers=2)
+    b, t, s = 1, 16, 256
+    psched = dsp_schedule(cfg, 4, t_len=t, s_len=s, batch=b,
+                          topology=mesh_topology(mesh, "ici"), joint=True)
+    opt_cfg = OptConfig()
+
+    def placed(tree):
+        specs = param_pspecs(tree, spec.plan, axis_sizes=dict(mesh.shape))
+        return jax.tree_util.tree_map(
+            lambda a, p: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                              sharding=NamedSharding(mesh, p)),
+            tree, specs)
+
+    params = jax.eval_shape(lambda: init_t2d(jax.random.PRNGKey(0), cfg))
+    opt = jax.eval_shape(lambda p: init_opt_state(p, opt_cfg), params)
+    rep = NamedSharding(mesh, P())
+    video = jax.ShapeDtypeStruct((b, t, s, cfg.in_dim), jnp.float32,
+                                 sharding=rep)
+    batch = {"x": video, "t": jax.ShapeDtypeStruct((b,), jnp.float32,
+                                                   sharding=rep),
+             "target": video}
+
+    def loss_fn(p, bb):
+        return t2d_loss(p, bb, cfg, mesh=mesh, schedule=psched)
+
+    step = jax.jit(make_train_step(loss_fn, opt_cfg), donate_argnums=(0, 1))
+    hlo = step.lower(placed(params), placed(opt), batch).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+    def leg(ln):
+        if "rematted_computation" in ln:
+            return "recompute"
+        return "bwd" if "transpose(" in ln else "fwd"
+
+    def a2a(where):
+        return parse_data_collectives(hlo, where=where).by_kind_count.get(
+            "all-to-all", 0)
+
+    table = collections.Counter(
+        (op_scope(ln) or "none", leg(ln)) for ln in hlo.splitlines()
+        if " all-to-all(" in ln or " all-to-all-start(" in ln)
+    print("\nall-to-alls by (scope, leg):", dict(table))
+    ex = ScheduleExecutor(psched, backend="auto", ctx=from_mesh(mesh))
+    planned_fwd = ex.expected_collectives(1)["all-to-all"]
+    planned_bwd = ex.expected_bwd_collectives(1)["all-to-all"]
+    switch = a2a(lambda ln: op_scope(ln) == "dsp_switch")
+    fwd = a2a(lambda ln: leg(ln) == "fwd")
+    recompute = a2a(lambda ln: leg(ln) == "recompute")
+    assert fwd == planned_fwd
+    assert 0 < recompute <= planned_fwd
+    assert switch == fwd + recompute
+    assert a2a(lambda ln: leg(ln) == "bwd" and "/sharding_constraint" in ln
+               and op_scope(ln) in ("spatial", "temporal")) == planned_bwd
+    unplanned = {sc for (sc, lg) in table
+                 if lg == "bwd" and sc not in ("spatial", "temporal")}
+    assert unplanned == {"mlp"}, table
