@@ -17,6 +17,12 @@ position) and prefill.
 Backward runs through the ``attention_ref`` oracle via a custom VJP defined
 in ops.py (recompute-based), which is the standard TPU approach when the
 forward is the hot spot being optimised.
+
+``ops.flash_attention`` runs this kernel only when the keys span more than
+one KV block.  With a single block the online softmax has no second block
+to rescale against, and the grid (one program per sequence and head) and
+the padding of the keys to the block are the whole cost, so ops.py runs
+that forward as XLA's fused attention instead.
 """
 from __future__ import annotations
 

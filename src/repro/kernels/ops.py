@@ -7,6 +7,14 @@ Each op:
     XLA's cost model accounts the FLOPs),
   * defines a custom VJP whose backward recomputes through the reference —
     the standard scope-control trade on TPU when the forward is the hot spot.
+
+Attention's forward picks its implementation from the shape: the flash
+kernel only when the keys span more than one KV block.  Within a single
+block flash has nothing to tile (no second block to rescale against, and
+scores no larger than (Sq, block_k)), so the kernel would only pay for its
+grid and for padding the keys to the block; there the forward runs as
+fused attention that XLA compiles, with the kernel's masking and f32
+softmax, under the scope ``tracing.ATTN_XLA``.
 """
 from __future__ import annotations
 
@@ -18,7 +26,7 @@ import jax.numpy as jnp
 
 from repro import tracing
 from repro.kernels import ref as _ref
-from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.flash_attention import MASK_VALUE, flash_attention_fwd
 from repro.kernels.ssd_scan import ssd_scan_fwd
 
 
@@ -53,15 +61,19 @@ def _make_attention(causal: bool, window: Optional[int],
             return ref_fn(q, k, v)
         b, hq, sq, d = q.shape
         skv = k.shape[2]
+        if skv <= block_k:
+            with tracing.scope(tracing.ATTN_XLA):
+                return _fused_attention(q, k, v, causal=causal,
+                                        window=window, softcap=softcap,
+                                        scale=scale, q_offset=q_offset)
         bq = min(block_q, _round_up(sq, 8))
-        bk = min(block_k, _round_up(skv, 128))
         qp = _pad_to(q, 2, bq)
-        kp = _pad_to(k, 2, bk)
-        vp = _pad_to(v, 2, bk)
+        kp = _pad_to(k, 2, block_k)
+        vp = _pad_to(v, 2, block_k)
         out = flash_attention_fwd(qp, kp, vp, causal=causal, window=window,
                                   softcap=softcap, scale=scale,
                                   q_offset=q_offset, kv_len=skv,
-                                  block_q=bq, block_k=bk)
+                                  block_q=bq, block_k=block_k)
         return out[:, :, :sq]
 
     @jax.custom_vjp
@@ -85,6 +97,38 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+def _fused_attention(q, k, v, *, causal, window, softcap, scale, q_offset):
+    """The flash kernel's forward over a single KV block, as two batched
+    dots XLA fuses: the same products (bf16 operands are upcast exactly,
+    f32 accumulation, f32 probabilities into p·v), the same masks, and a
+    row with no key left gives zeros, as the kernel's does."""
+    b, hq, sq, d = q.shape
+    _, hkv, skv, _ = k.shape
+    g = hq // hkv
+    scale = d ** -0.5 if scale is None else scale
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.einsum("bhgqd,bhkd->bhgqk", q.reshape(b, hkv, g, sq, d), k,
+                   precision=hi, preferred_element_type=jnp.float32) * scale
+    if softcap is not None:
+        s = softcap * jnp.tanh(s / softcap)
+    rows = q_offset + jnp.arange(sq)[:, None]
+    cols = jnp.arange(skv)[None, :]
+    mask = jnp.ones((sq, skv), bool)
+    if causal:
+        mask &= cols <= rows
+    if window is not None:
+        mask &= cols > rows - window
+    s = jnp.where(mask, s, MASK_VALUE)
+    p = jnp.where(mask, jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
+    l = p.sum(-1, keepdims=True)
+    o = jnp.einsum("bhgqk,bhkd->bhgqd", p, v.astype(jnp.float32),
+                   precision=hi, preferred_element_type=jnp.float32)
+    # l >= 1 where a key is left (the row's max adds exp(0)); a row with
+    # none has l = 0 and o = 0, which stays 0
+    o = o / jnp.maximum(l, 1.0)
+    return o.reshape(b, hq, sq, d).astype(q.dtype)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False, window: Optional[int] = None,
                     softcap: Optional[float] = None,
@@ -94,7 +138,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """Multi-head attention; q: (B, Hq, Sq, D), k/v: (B, Hkv, Skv, D).
 
     backend: "pallas" (kernel; interpret-mode off-TPU) or "ref" (pure jnp —
-    used by the dry-run/roofline so XLA accounts the FLOPs).
+    used by the dry-run/roofline so XLA accounts the FLOPs).  Under
+    "pallas" the flash kernel runs when Skv > block_k; when every key fits
+    in one KV block (e.g. the DiT's temporal attention over T frames) the
+    forward is XLA's fused attention with the same masks and precision, as
+    a single block gives flash nothing to tile.
     """
     fn = _make_attention(causal, window, softcap, scale, q_offset,
                          block_q, block_k, backend)

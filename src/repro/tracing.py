@@ -33,6 +33,7 @@ DSP_SWITCH = "dsp_switch"  # every planned layout transition (core/schedule)
 
 SCOPES = (LAYERS, SPATIAL, TEMPORAL, ADALN, PROJ, ATTN, MLP, ATTN_BWD, ADAMW,
           EMBED, LOSS, DSP_SWITCH)
+ATTN_XLA = "attn_xla"    # inside attn, not a stage: XLA's forward, no kernel
 
 # -- spans: host time of one training step (train/trainer.py) ----------------
 STEP = "train"           # the StepTraceAnnotation around each step

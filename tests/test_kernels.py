@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
+from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.ops import flash_attention, ssd_scan
 
 KEY = jax.random.PRNGKey(0)
@@ -24,7 +25,13 @@ ATTN_CASES = [
     (2, 6, 3, 80, 80, 16, True, None, None),         # non-128 dims
     (1, 2, 2, 1, 300, 64, True, None, None),         # decode-like Sq=1
     (1, 4, 4, 128, 128, 128, True, 64, 50.0),        # everything on
+    (32, 16, 16, 16, 16, 72, False, None, None),     # DiT temporal, T=16
 ]
+
+
+def _runs_kernel(fn, *args):
+    """Whether the traced op holds the Pallas kernel (a ``pallas_call``)."""
+    return "pallas_call" in str(jax.make_jaxpr(fn)(*args))
 
 
 @pytest.mark.parametrize("case", ATTN_CASES)
@@ -39,6 +46,49 @@ def test_flash_attention_matches_ref(case, dtype):
                           softcap=softcap, q_offset=qoff)
     want = ref.attention_ref(q, k, v, causal=causal, window=window,
                              softcap=softcap, q_offset=qoff)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("skv,kernel", [(128, False), (129, True)],
+                         ids=["one_block_xla", "two_blocks_kernel"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_attention_path_follows_the_kv_block(skv, kernel, dtype):
+    """Keys that fit one KV block run XLA's fused attention; one key more
+    runs the kernel.  Both match the reference with causal, window,
+    softcap and GQA on."""
+    b, hq, hkv, sq, d = 1, 4, 2, 64, 32
+    q = rand((b, hq, sq, d), 4, dtype)
+    k = rand((b, hkv, skv, d), 5, dtype)
+    v = rand((b, hkv, skv, d), 6, dtype)
+    kw = dict(causal=True, window=48, softcap=30.0, q_offset=skv - sq)
+
+    def f(q, k, v):
+        return flash_attention(q, k, v, **kw)
+
+    assert _runs_kernel(f, q, k, v) == kernel
+    want = ref.attention_ref(q, k, v, **kw)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(f(q, k, v), np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_kernel_over_padded_short_kv(dtype):
+    """The kernel itself at the DiT's temporal shape, keys padded to one
+    128 block and masked by ``kv_len`` (``flash_attention`` no longer sends
+    it such short keys)."""
+    b, h, l, d = 4, 2, 16, 72
+    q = rand((b, h, l, d), 7, dtype)
+    k = rand((b, h, l, d), 8, dtype)
+    v = rand((b, h, l, d), 9, dtype)
+    pad = ((0, 0), (0, 0), (0, 128 - l), (0, 0))
+    out = flash_attention_fwd(q, jnp.pad(k, pad), jnp.pad(v, pad),
+                              kv_len=l, block_q=l)
+    want = ref.attention_ref(q, k, v)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),
@@ -68,6 +118,32 @@ def test_flash_attention_grads_match_ref():
     gr = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gk, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("case", [
+    # B, Hq, Hkv, L, D, causal, window, softcap
+    (8, 16, 16, 16, 72, False, None, None),          # DiT temporal
+    (2, 4, 2, 100, 32, True, 37, 30.0),              # GQA, everything on
+])
+def test_one_block_attention_grads_match_ref(case):
+    b, hq, hkv, l, d, causal, window, softcap = case
+    q = rand((b, hq, l, d), 11)
+    k = rand((b, hkv, l, d), 12)
+    v = rand((b, hkv, l, d), 13)
+    kw = dict(causal=causal, window=window, softcap=softcap)
+
+    def f_xla(q, k, v):
+        return (flash_attention(q, k, v, **kw) * v[:, :1]).sum()
+
+    def f_ref(q, k, v):
+        return (ref.attention_ref(q, k, v, **kw) * v[:, :1]).sum()
+
+    assert not _runs_kernel(jax.grad(f_xla, argnums=(0, 1, 2)), q, k, v)
+    gx = jax.grad(f_xla, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(gx, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=1e-4, rtol=1e-4)
 
 

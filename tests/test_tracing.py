@@ -87,6 +87,49 @@ def test_attention_backward_and_optimizer_carry_their_scopes(smoke_step_hlo):
     assert sqrt and set(sqrt) == {"adamw"}
 
 
+@pytest.fixture(scope="module")
+def wide_frame_step_hlo():
+    """The SMOKE step (two layers) at T=4, S=256: temporal attention's keys
+    fit one KV block, spatial attention's span two."""
+    from repro.launch.train import build, parse_args
+    trainer, _ = build(parse_args(
+        ["--arch", "transformer2d-720m", "--batch", "1", "--temporal", "4",
+         "--spatial", "256", "--steps", "2"]))
+    batch = trainer.data_fn(0)
+    return trainer.step_fn.lower(trainer.params, trainer.opt_state,
+                                 batch).compile().as_text()
+
+
+def test_attention_forward_runs_the_kernel_only_past_one_kv_block(
+        wide_frame_step_hlo):
+    from repro import tracing
+    from repro.analysis.roofline import op_scope
+
+    def kernel(name, ln):     # the Pallas call (interpreted here) or its op
+        return "flash_fwd" in name or "tpu_custom_call" in ln
+
+    fwd = {}
+    for axis in (tracing.TEMPORAL, tracing.SPATIAL):
+        fwd[axis] = [(op, name, ln) for op, name, ln in _ops(wide_frame_step_hlo)
+                     if f"/{axis}/{tracing.ATTN}/" in name
+                     and tracing.ATTN_BWD not in name and not _backward(name)]
+    # temporal: XLA's fused attention, no kernel; only the layout
+    # transposes around it (models/transformer2d.py) sit outside attn_xla
+    temporal = fwd[tracing.TEMPORAL]
+    assert not [name for _, name, ln in temporal if kernel(name, ln)]
+    assert not [name for _, name, _ in temporal
+                if f"/{tracing.ATTN_XLA}/" not in name
+                and not name.endswith("/transpose")]
+    assert sum(op == "dot" for op, _, _ in temporal) >= 2 * 2
+    # the dots still read as the stage ``attn``
+    assert {op_scope(ln) for op, _, ln in temporal if op == "dot"} == {
+        tracing.ATTN}
+    # spatial: the flash kernel, and nothing of the XLA path
+    spatial = fwd[tracing.SPATIAL]
+    assert any(kernel(name, ln) for _, name, ln in spatial)
+    assert not [name for _, name, _ in spatial if tracing.ATTN_XLA in name]
+
+
 def _host_events(trace_dir):
     from jax.profiler import ProfileData
     path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
