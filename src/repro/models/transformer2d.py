@@ -29,6 +29,7 @@ and never issues a switch or stage-boundary constraint itself.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -714,6 +715,13 @@ def forward(params, x, t, cfg: T2DConfig, *, mesh: Optional[Mesh] = None,
     layers = params["layers"]
     n = jax.tree_util.tree_leaves(layers)[0].shape[0]
 
+    def layer_params(stack, i):
+        # on a mesh the stack is ZeRO-sharded: name where a layer's weights
+        # leave it (the partitioner's gathers keep the name of their dot)
+        with (tracing.scope(tracing.ZERO) if mesh is not None
+              else contextlib.nullcontext()):
+            return jax.tree_util.tree_map(lambda a: a[i], stack)
+
     if isinstance(psched, UnrolledSchedule):
         # non-periodic plan: python-unroll the layer loop; boundaries (and
         # anchors) address stages by ABSOLUTE index so every layer pair may
@@ -736,7 +744,7 @@ def forward(params, x, t, cfg: T2DConfig, *, mesh: Optional[Mesh] = None,
 
         with tracing.scope(tracing.LAYERS):
             for i in range(n):
-                lp = jax.tree_util.tree_map(lambda a: a[i], layers)
+                lp = layer_params(layers, i)
                 body = (jax.checkpoint(functools.partial(pair_body, i=i),
                                        prevent_cse=False)
                         if remat else functools.partial(pair_body, i=i))
@@ -764,8 +772,7 @@ def forward(params, x, t, cfg: T2DConfig, *, mesh: Optional[Mesh] = None,
 
         def group_body(xc, gp):
             for i in range(g):
-                xi = jax.tree_util.tree_map(lambda a: a[i], gp)
-                xc, _ = layer_body(xc, xi)
+                xc, _ = layer_body(xc, layer_params(gp, i))
             return xc, None
 
         grouped = jax.tree_util.tree_map(

@@ -7,7 +7,11 @@ nothing at run time.  Name stacks survive ``jvp``, ``transpose``,
 ``checkpoint`` and ``scan``: a backward op's name carries a ``transpose(``
 wrapper, and remat's recompute sits under ``rematted_computation``.  Ops the
 SPMD partitioner inserts (resharding all-to-alls, ZeRO gathers) inherit the
-scope of the op they serve.
+scope of the op they serve: a weight gather that of the dot it feeds, a
+gradient reduce-scatter that of the backward's dot, or none.  So ``zero``
+names where a layer's ZeRO-sharded weights leave their stack, not the
+collectives: naming those would take a sharding constraint, and any
+constraint on the weights re-lays out the step.
 
 A span (``span(name)``, which is ``jax.profiler.TraceAnnotation``) marks an
 interval of host time in the profiler's trace, on the device trace's clock.
@@ -30,9 +34,11 @@ ADAMW = "adamw"          # the optimizer, global-norm clip included
 EMBED = "embed"          # patch, position and timestep embeddings
 LOSS = "loss"            # final norm, head and the MSE
 DSP_SWITCH = "dsp_switch"  # every planned layout transition (core/schedule)
+ZERO = "zero"            # on a mesh: a layer's ZeRO-sharded weights sliced
+                         # from the stack, their gradients stacked back
 
 SCOPES = (LAYERS, SPATIAL, TEMPORAL, ADALN, PROJ, ATTN, MLP, ATTN_BWD, ADAMW,
-          EMBED, LOSS, DSP_SWITCH)
+          EMBED, LOSS, DSP_SWITCH, ZERO)
 ATTN_XLA = "attn_xla"    # inside attn, not a stage: XLA's forward, no kernel
 
 # -- spans: host time of one training step (train/trainer.py) ----------------

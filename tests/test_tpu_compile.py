@@ -2,12 +2,13 @@
 not attached: the TPU compiler refuses here what interpret mode on the CPU
 accepts (misaligned tiles, too much VMEM).  Shapes are the 720M DiT's
 (16 heads of 72) at the training smoke shape and the paper's spatial
-extent.
+extent, and the 3B DiT's (32 heads of 64) as one chip of four sees it.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
 file.  Keep all such compiles in this one file.
 """
+import contextlib
 import functools
 import os
 
@@ -43,13 +44,13 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile_fwd(one_chip, batch, q_len, kv_len, kv_valid):
+def _compile_fwd(one_chip, batch, q_len, kv_len, kv_valid, heads=H, dh=D):
     import jax
     import jax.numpy as jnp
     from repro.kernels.flash_attention import flash_attention_fwd
-    q = jax.ShapeDtypeStruct((batch, H, q_len, D), jnp.bfloat16,
+    q = jax.ShapeDtypeStruct((batch, heads, q_len, dh), jnp.bfloat16,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((batch, H, kv_len, D), jnp.bfloat16,
+    kv = jax.ShapeDtypeStruct((batch, heads, kv_len, dh), jnp.bfloat16,
                               sharding=one_chip)
     fn = functools.partial(flash_attention_fwd, kv_len=kv_valid,
                            block_q=min(128, q_len), interpret=False)
@@ -65,33 +66,24 @@ def test_spatial_stage_kernel_compiles(one_chip, batch, s_len):
 
 
 def test_temporal_stage_kernel_compiles(one_chip):
-    # temporal attention: (B*S) sequences of T=16 frames, KV padded to one
-    # 128 block (kernels/ops.py runs this forward as XLA's attention; the
-    # kernel still compiles at the shape)
-    hlo = _compile_fwd(one_chip, 256, 16, 128, 16)
+    # temporal attention (T=16, one KV block) runs as XLA's attention
+    # (kernels/ops.py), so the kernel's shape in the 3B four-chip cell is
+    # spatial attention per chip under a (1, 4) DSP mesh: the switch leaves
+    # each chip B*T/4 = 4 frames of all S=1024 tokens, 32 heads of 64
+    hlo = _compile_fwd(one_chip, 4, 1024, 1024, 1024, heads=32, dh=64)
     assert "tpu_custom_call" in hlo
 
 
-def test_planned_switches_of_the_sharded_train_step_carry_their_scope(
-        topo, monkeypatch):
+def _lower_sharded_step(topo):
     """The 720M train step at full width, cut to one spatial and one
-    temporal block, AOT-compiled for a (1, 4) DSP mesh of the v5e:2x2.
-    Every all-to-all of the forward, and of remat's recompute, is a planned
-    switch and carries ``dsp_switch``.  Each planned backward switch lands
-    on a block-end anchor (the mirrored plan's transposed boundary keeps
-    the cotangent's layout; see tests/test_hlo_collectives.py).  The
-    all-to-alls that no plan accounts for carry ``mlp``: they come from the
-    FFN's backward.  ``pytest -s`` prints the table by scope."""
-    import collections
+    temporal block, lowered for a (1, 4) DSP mesh of the v5e:2x2 with the
+    kernel compiled (not interpreted); returns (lowered, schedule, mesh)."""
     import dataclasses
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
     from repro import configs
-    from repro.analysis.roofline import op_scope, parse_data_collectives
-    from repro.core.layout import from_mesh
-    from repro.core.schedule import ScheduleExecutor
     from repro.kernels import flash_attention as fa, ops
     from repro.launch.mesh import mesh_topology
     from repro.models.transformer2d import dsp_schedule, init_t2d, t2d_loss
@@ -99,8 +91,6 @@ def test_planned_switches_of_the_sharded_train_step_carry_their_scope(
     from repro.parallel.partition import param_pspecs
     from repro.train.trainer import make_train_step
 
-    monkeypatch.setattr(ops, "flash_attention_fwd", functools.partial(
-        fa.flash_attention_fwd, interpret=False))
     mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
                 axis_types=(AxisType.Auto,) * 2)
     spec = configs.get("transformer2d-720m")
@@ -130,7 +120,35 @@ def test_planned_switches_of_the_sharded_train_step_carry_their_scope(
         return t2d_loss(p, bb, cfg, mesh=mesh, schedule=psched)
 
     step = jax.jit(make_train_step(loss_fn, opt_cfg), donate_argnums=(0, 1))
-    hlo = step.lower(placed(params), placed(opt), batch).compile().as_text()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "flash_attention_fwd", functools.partial(
+            fa.flash_attention_fwd, interpret=False))
+        lowered = step.lower(placed(params), placed(opt), batch)
+    return lowered, psched, mesh
+
+
+@pytest.fixture(scope="module")
+def sharded_step(topo):
+    """(compiled HLO text, schedule, mesh) of ``_lower_sharded_step``."""
+    lowered, psched, mesh = _lower_sharded_step(topo)
+    return lowered.compile().as_text(), psched, mesh
+
+
+def test_planned_switches_of_the_sharded_train_step_carry_their_scope(
+        sharded_step):
+    """Every all-to-all of the forward, and of remat's recompute, is a
+    planned switch and carries ``dsp_switch``.  Each planned backward
+    switch lands on a block-end anchor (the mirrored plan's transposed
+    boundary keeps the cotangent's layout; see
+    tests/test_hlo_collectives.py).  The all-to-alls that no plan accounts
+    for carry ``mlp``: they come from the FFN's backward.  ``pytest -s``
+    prints the table by scope."""
+    import collections
+    from repro.analysis.roofline import op_scope, parse_data_collectives
+    from repro.core.layout import from_mesh
+    from repro.core.schedule import ScheduleExecutor
+
+    hlo, psched, mesh = sharded_step
     assert "tpu_custom_call" in hlo
 
     def leg(ln):
@@ -160,3 +178,41 @@ def test_planned_switches_of_the_sharded_train_step_carry_their_scope(
     unplanned = {sc for (sc, lg) in table
                  if lg == "bwd" and sc not in ("spatial", "temporal")}
     assert unplanned == {"mlp"}, table
+
+
+def test_zero_scope_names_the_layer_slices_and_relays_out_nothing(
+        topo, sharded_step, monkeypatch):
+    """``zero`` names where each layer's ZeRO-sharded weights leave their
+    stack, and changes nothing but names: the step lowered without it is
+    the same program.  No collective carries it: the partitioner names a
+    weight gather after the dot it feeds (``mlp``, ``proj``) and leaves
+    the gradient reduce-scatters unnamed, which is why the benchmark reads
+    ZeRO's collectives by kind.  ``pytest -s`` prints them by scope."""
+    import collections
+    import re
+    from repro import tracing
+    from repro.analysis.roofline import op_scope
+
+    hlo = sharded_step[0]
+    named = [ln for ln in hlo.splitlines()
+             if re.search(r'op_name="[^"]*/zero/', ln)]
+    assert named
+    kinds = ("all-gather", "reduce-scatter", "collective-permute",
+             "all-reduce")
+    found = collections.Counter()
+    for ln in hlo.splitlines():
+        m = re.match(r"\s*(ROOT )?%[\w.\-]+ = .*? ([a-z][a-z0-9\-]*)\(", ln)
+        kind = m and re.sub(r"-start$", "", m.group(2))
+        if kind in kinds:
+            found[(kind, op_scope(ln) or "none")] += 1
+    print("\nZeRO's collectives by (kind, scope):", dict(found))
+    assert found and not any(sc == tracing.ZERO for _, sc in found)
+    assert {sc for kind, sc in found if kind == "all-gather"} >= {"mlp",
+                                                                   "proj"}
+
+    real = tracing.scope
+    monkeypatch.setattr(tracing, "scope", lambda name: (
+        contextlib.nullcontext() if name == tracing.ZERO else real(name)))
+    without = _lower_sharded_step(topo)[0].as_text()
+    monkeypatch.setattr(tracing, "scope", real)
+    assert _lower_sharded_step(topo)[0].as_text() == without
