@@ -9,17 +9,20 @@ Each op:
     the standard scope-control trade on TPU when the forward is the hot spot.
 
 Attention's forward picks its implementation from the shape: the flash
-kernel only when the keys span more than one KV block.  Within a single
-block flash has nothing to tile (no second block to rescale against, and
-scores no larger than (Sq, block_k)), so the kernel would only pay for its
-grid and for padding the keys to the block; there the forward runs as
-fused attention that XLA compiles, with the kernel's masking and f32
-softmax, under the scope ``tracing.ATTN_XLA``.
+kernel only when the keys span more than one 128-key block (``KV_BLOCK``).
+Within one such block flash has nothing to tile, so the kernel would only
+pay for its grid and for padding the keys to the block; there the forward
+runs as fused attention that XLA compiles, with the kernel's masking and
+f32 softmax, under the scope ``tracing.ATTN_XLA``.  Past it,
+``flash_tiles`` picks the kernel's tiles from the shape alone: lengths pad
+to a multiple of 128 and each tile is the largest of 1024/512/256/128 rows
+that divides its padded length.  Both of the kernel's dots take f32
+operands at ``HIGHEST`` (``flash_attention``'s module notes).
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,11 +47,26 @@ def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
 # Flash attention
 # ---------------------------------------------------------------------------
 
+KV_BLOCK = 128      # keys up to one block run as XLA's fused attention
+TILES = (1024, 512, 256, 128)
+
+
+def flash_tiles(sq: int, skv: int) -> Tuple[int, int]:
+    """(block_q, block_k) of the flash kernel for these lengths.
+
+    Lengths pad to a multiple of 128, never further, and each tile is the
+    largest of ``TILES`` that divides its padded length (a key tile that
+    covers every key needs no online rescale).  A short query (decode:
+    Sq <= 128) keeps one tile of Sq rounded to 8."""
+    def largest(n):
+        return next(t for t in TILES if _round_up(n, 128) % t == 0)
+    return (_round_up(sq, 8) if sq <= 128 else largest(sq)), largest(skv)
+
+
 @functools.lru_cache(maxsize=None)
 def _make_attention(causal: bool, window: Optional[int],
                     softcap: Optional[float], scale: Optional[float],
-                    q_offset: int, block_q: int, block_k: int,
-                    backend: str):
+                    q_offset: int, backend: str):
     """Build a custom-VJP attention fn for a static config (cached)."""
 
     def ref_fn(q, k, v):
@@ -61,19 +79,17 @@ def _make_attention(causal: bool, window: Optional[int],
             return ref_fn(q, k, v)
         b, hq, sq, d = q.shape
         skv = k.shape[2]
-        if skv <= block_k:
+        if skv <= KV_BLOCK:
             with tracing.scope(tracing.ATTN_XLA):
                 return _fused_attention(q, k, v, causal=causal,
                                         window=window, softcap=softcap,
                                         scale=scale, q_offset=q_offset)
-        bq = min(block_q, _round_up(sq, 8))
-        qp = _pad_to(q, 2, bq)
-        kp = _pad_to(k, 2, block_k)
-        vp = _pad_to(v, 2, block_k)
-        out = flash_attention_fwd(qp, kp, vp, causal=causal, window=window,
-                                  softcap=softcap, scale=scale,
-                                  q_offset=q_offset, kv_len=skv,
-                                  block_q=bq, block_k=block_k)
+        bq, bk = flash_tiles(sq, skv)
+        out = flash_attention_fwd(_pad_to(q, 2, bq), _pad_to(k, 2, bk),
+                                  _pad_to(v, 2, bk), causal=causal,
+                                  window=window, softcap=softcap,
+                                  scale=scale, q_offset=q_offset,
+                                  kv_len=skv, block_q=bq, block_k=bk)
         return out[:, :, :sq]
 
     @jax.custom_vjp
@@ -133,19 +149,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False, window: Optional[int] = None,
                     softcap: Optional[float] = None,
                     scale: Optional[float] = None, q_offset: int = 0,
-                    block_q: int = 128, block_k: int = 128,
                     backend: str = "pallas") -> jax.Array:
     """Multi-head attention; q: (B, Hq, Sq, D), k/v: (B, Hkv, Skv, D).
 
     backend: "pallas" (kernel; interpret-mode off-TPU) or "ref" (pure jnp —
     used by the dry-run/roofline so XLA accounts the FLOPs).  Under
-    "pallas" the flash kernel runs when Skv > block_k; when every key fits
-    in one KV block (e.g. the DiT's temporal attention over T frames) the
-    forward is XLA's fused attention with the same masks and precision, as
-    a single block gives flash nothing to tile.
+    "pallas" the flash kernel runs, on the tiles ``flash_tiles`` picks,
+    when Skv > ``KV_BLOCK``; when every key fits in one such block (e.g.
+    the DiT's temporal attention over T frames) the forward is XLA's fused
+    attention with the same masks and precision.
     """
-    fn = _make_attention(causal, window, softcap, scale, q_offset,
-                         block_q, block_k, backend)
+    fn = _make_attention(causal, window, softcap, scale, q_offset, backend)
     return fn(q, k, v)
 
 

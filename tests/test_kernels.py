@@ -1,5 +1,7 @@
 """Per-kernel correctness: shape/dtype sweeps against the pure-jnp oracles
 (interpret mode executes the Pallas kernel body on CPU)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,7 +9,7 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_fwd
-from repro.kernels.ops import flash_attention, ssd_scan
+from repro.kernels.ops import flash_attention, flash_tiles, ssd_scan
 
 KEY = jax.random.PRNGKey(0)
 
@@ -26,6 +28,9 @@ ATTN_CASES = [
     (1, 2, 2, 1, 300, 64, True, None, None),         # decode-like Sq=1
     (1, 4, 4, 128, 128, 128, True, 64, 50.0),        # everything on
     (32, 16, 16, 16, 16, 72, False, None, None),     # DiT temporal, T=16
+    (1, 4, 2, 200, 600, 32, True, 256, None),        # many KV tiles, padded
+    (2, 2, 2, 256, 256, 72, False, None, None),      # DiT spatial, S=256
+    (1, 2, 2, 1024, 1024, 64, False, None, None),    # 3B spatial, S=1024
 ]
 
 
@@ -95,14 +100,75 @@ def test_flash_kernel_over_padded_short_kv(dtype):
                                atol=tol, rtol=tol)
 
 
-def test_flash_attention_block_shapes():
-    """Same numerics across VMEM tiling choices."""
-    q, k, v = (rand((1, 2, 256, 64), i) for i in range(3))
-    base = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
-    for bq, bk in [(64, 64), (128, 256), (256, 128)]:
-        out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
+@pytest.mark.parametrize("s_len,d,causal,tiles", [
+    (256, 64, True, [(64, 64), (128, 256), (256, 128),
+                     flash_tiles(256, 256)]),
+    (1024, 64, False, [(256, 256), (1024, 512),
+                       flash_tiles(1024, 1024)]),
+], ids=["S256-causal", "S1024"])
+def test_flash_attention_block_shapes(s_len, d, causal, tiles):
+    """Same numerics across VMEM tiling choices, the rule's among them."""
+    q, k, v = (rand((1, 2, s_len, d), i) for i in range(3))
+    base = flash_attention_fwd(q, k, v, causal=causal, block_q=128,
+                               block_k=128)
+    for bq, bk in tiles:
+        out = flash_attention_fwd(q, k, v, causal=causal, block_q=bq,
+                                  block_k=bk)
         np.testing.assert_allclose(np.asarray(out), np.asarray(base),
                                    atol=1e-5, rtol=1e-5)
+
+
+def test_flash_tiles_follow_the_shape():
+    """Tiles come from the lengths alone: the cells' spatial lengths,
+    keys one past a block, decode, and never padding past a multiple of
+    128."""
+    assert flash_tiles(256, 256) == (256, 256)
+    assert flash_tiles(1024, 1024) == (1024, 1024)
+    assert flash_tiles(129, 129)[1] == 256
+    assert flash_tiles(1, 300) == (8, 128)
+    for sq in (129, 200, 256, 384, 640, 1000, 1024, 1536, 4096):
+        for skv in (129, 300, 512, 768, 1024, 2000, 4096):
+            bq, bk = flash_tiles(sq, skv)
+            assert -(-sq // bq) * bq == -(-sq // 128) * 128, (sq, bq)
+            assert -(-skv // bk) * bk == -(-skv // 128) * 128, (skv, bk)
+
+
+def _eqns(jaxpr, name):
+    """Every equation of primitive ``name`` in ``jaxpr`` and the jaxprs
+    nested in it."""
+    for e in jaxpr.eqns:
+        if e.primitive.name == name:
+            yield e
+        for p in e.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub, name)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("b,hq,hkv,s_len,d,causal,window", [
+    (2, 2, 2, 256, 72, False, None), (1, 2, 2, 1024, 64, False, None),
+    (1, 4, 2, 600, 32, True, 256),
+], ids=["S256", "S1024", "many-kv-tiles"])
+def test_kernel_dots_take_f32_at_highest(b, hq, hkv, s_len, d, causal,
+                                         window, dtype):
+    """Both of the kernel's dots take f32 operands at ``HIGHEST``.  At the
+    default precision the TPU rounds an f32 operand (q, k, p, v) to one
+    bf16; interpret mode computes in f32 either way, so the numbers cannot
+    show it."""
+    q = rand((b, hq, s_len, d), 50, dtype)
+    k, v = (rand((b, hkv, s_len, d), 51 + i, dtype) for i in range(2))
+    jaxpr = jax.make_jaxpr(functools.partial(
+        flash_attention, causal=causal, window=window))(q, k, v)
+    kernels = list(_eqns(jaxpr.jaxpr, "pallas_call"))
+    assert len(kernels) == 1
+    dots = list(_eqns(kernels[0].params["jaxpr"], "dot_general"))
+    assert len(dots) == 2
+    highest = jax.lax.Precision.HIGHEST
+    for e in dots:
+        assert [x.aval.dtype for x in e.invars] == [jnp.float32] * 2
+        assert e.params["precision"] == (highest, highest)
 
 
 def test_flash_attention_grads_match_ref():

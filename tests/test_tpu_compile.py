@@ -44,24 +44,34 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile_fwd(one_chip, batch, q_len, kv_len, kv_valid, heads=H, dh=D):
+def _compile_fwd(one_chip, batch, q_len, kv_len, kv_valid, heads=H, dh=D,
+                 dtype="bfloat16"):
+    """The kernel on the tiles ``ops.flash_tiles`` picks for the shape."""
     import jax
     import jax.numpy as jnp
     from repro.kernels.flash_attention import flash_attention_fwd
-    q = jax.ShapeDtypeStruct((batch, heads, q_len, dh), jnp.bfloat16,
+    from repro.kernels.ops import flash_tiles
+    q = jax.ShapeDtypeStruct((batch, heads, q_len, dh), dtype,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((batch, heads, kv_len, dh), jnp.bfloat16,
+    kv = jax.ShapeDtypeStruct((batch, heads, kv_len, dh), dtype,
                               sharding=one_chip)
+    bq, bk = flash_tiles(q_len, kv_valid)
     fn = functools.partial(flash_attention_fwd, kv_len=kv_valid,
-                           block_q=min(128, q_len), interpret=False)
+                           block_q=bq, block_k=bk, interpret=False)
     return jax.jit(fn).lower(q, kv, kv).compile().as_text()
 
 
-@pytest.mark.parametrize("batch,s_len", [(16, 256), (1, 4096)],
-                         ids=["S256", "S4096"])
-def test_spatial_stage_kernel_compiles(one_chip, batch, s_len):
+@pytest.mark.parametrize("batch,s_len,heads,dh,dtype", [
+    (16, 256, H, D, "bfloat16"), (1, 4096, H, D, "bfloat16"),
+    # the DiT's activations are f32, so its cells feed the kernel f32
+    (16, 256, H, D, "float32"), (4, 1024, 32, 64, "float32"),
+    (1, 4096, H, D, "float32"),
+], ids=["S256", "S4096", "S256-f32", "S1024-3b-f32", "S4096-f32"])
+def test_spatial_stage_kernel_compiles(one_chip, batch, s_len, heads, dh,
+                                       dtype):
     # spatial attention: (B*T) sequences of S tokens
-    hlo = _compile_fwd(one_chip, batch, s_len, s_len, s_len)
+    hlo = _compile_fwd(one_chip, batch, s_len, s_len, s_len, heads=heads,
+                       dh=dh, dtype=dtype)
     assert "tpu_custom_call" in hlo
 
 
